@@ -7,8 +7,8 @@ Two questions, answered per shape:
    ``[Q, 16]`` scalar block once and writes ``[Q, 64]`` — so achieved
    bytes/s against :data:`repro.analysis.roofline.HBM_BW` is the honest
    utilization number (``kernel_roofline``).  On this host the kernel runs
-   in the backend-resolved execution mode (``ops.INTERPRET``: compiled on
-   TPU, interpret elsewhere), and the mode is reported with every row.
+   in the backend-resolved execution mode (``ops.interpret_mode()``:
+   compiled on TPU, interpret elsewhere), and the mode is reported with every row.
 
 2. **Is the compiled-signature set actually closed?**  A sweep over many
    distinct raw batch sizes is pushed through power-of-two bucketing
@@ -64,7 +64,7 @@ def _roofline_rows(shapes, reps: int) -> List[Dict]:
         rl = roofline.kernel_roofline(_fused_bytes(q, d), t)
         rows.append({
             "segment": "fused_roofline", "n_queries": q, "n_docs": d,
-            "block_q": block_q, "interpret": ops.INTERPRET,
+            "block_q": block_q, "interpret": ops.interpret_mode(),
             "us_per_call": t * 1e6,
             "achieved_bytes_per_s": rl["achieved_bytes_per_s"],
             "peak_bytes_per_s": rl["peak_bytes_per_s"],
@@ -72,7 +72,7 @@ def _roofline_rows(shapes, reps: int) -> List[Dict]:
             "new_compiles": bucketing.compile_count("fused_measures")
             - traces0,
         })
-        mode = "interp" if ops.INTERPRET else "compiled"
+        mode = "interp" if ops.interpret_mode() else "compiled"
         print(f"fused[{mode}] q={q} d={d} block_q={block_q}: "
               f"{t*1e3:.1f}ms  {rl['achieved_bytes_per_s']/1e9:.3f} GB/s "
               f"({100*rl['bw_fraction']:.4f}% of roofline)")

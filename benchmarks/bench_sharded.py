@@ -1,76 +1,58 @@
 """Sharded segment: evaluation throughput scaling vs. device count.
 
-Each device count runs in its own subprocess (the XLA host-platform device
-count must be fixed before jax initializes, exactly like
-``tests/test_distributed.py``) with
-``XLA_FLAGS=--xla_force_host_platform_device_count=N``.  The child builds one
-synthesized collection (paper §3 protocol), tokenizes the run once, and times
-the steady-state sharded step — ``ShardedEvaluator.evaluate_buffer`` on the
-cached ``RunBuffer``: numeric scatter → shard_map → fused kernel per shard →
-one psum.  ``speedup_vs_1dev`` is the wall-clock ratio against the 1-device
-subprocess.
+One process, one mesh per device count: the first 1, 2, 4 and 8 of
+``jax.devices()`` (counts above the visible devices are skipped).  Each
+mesh evaluates the same synthesized collection (paper §3 protocol),
+tokenized once, and times the steady-state sharded step —
+``ShardedEvaluator.evaluate_buffer`` on the cached ``RunBuffer``: numeric
+scatter → shard_map → fused kernel per shard → one psum.
+``speedup_vs_1dev`` is the wall-clock ratio against the 1-device mesh.
 
-Host-platform "devices" are CPU threads sharing one machine, so the scaling
-curve here is a plumbing/overhead check, not a hardware claim: it verifies
-the collective payload stays O(measures), and on a real TPU mesh the same
-code path shards the sort + fused kernel across chips.
+On a TPU host the devices are the chips.  On a CPU host there is one device
+unless the process starts with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; such "devices" are
+threads sharing one machine, so the curve is a plumbing/overhead check,
+not a hardware claim.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 from typing import Dict, List
 
-SRC = os.path.join(os.path.dirname(__file__), "..", "src")
-
-_CHILD = """
-import json, sys
-import numpy as np
-from repro.core import RelevanceEvaluator
-from repro.data.synthetic_ir import synthesize_run
-from repro.distributed import ShardedEvaluator
-from benchmarks.common import time_call
-
-n_queries, n_docs, reps = (int(x) for x in sys.argv[1:4])
-run, qrel = synthesize_run(n_queries, n_docs)
-ev = RelevanceEvaluator(qrel, ("map", "ndcg", "recip_rank", "P"))
-buf = ev.tokenize_run(run)
-sev = ShardedEvaluator(ev)
-t = time_call(lambda: sev.evaluate_buffer(buf), reps=reps)
-print(json.dumps({"devices": sev.n_shards, "sharded_us": t * 1e6}))
-"""
+DEVICE_COUNTS = (1, 2, 4, 8)
 
 
 def run(full: bool = False) -> List[Dict]:
+    import jax
+    from jax.sharding import Mesh
+
+    from benchmarks.common import time_call
+    from repro.core import RelevanceEvaluator
+    from repro.data.synthetic_ir import synthesize_run
+    from repro.distributed import ShardedEvaluator
+
     n_queries, n_docs = (2048, 1000) if full else (512, 256)
     reps = 10 if full else 3
+    run_, qrel = synthesize_run(n_queries, n_docs)
+    ev = RelevanceEvaluator(qrel, ("map", "ndcg", "recip_rank", "P"))
+    buf = ev.tokenize_run(run_)
+    devices = jax.devices()
     rows: List[Dict] = []
     base_us = None
-    for devices in (1, 2, 4, 8):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            [SRC, os.path.join(SRC, ".."), env.get("PYTHONPATH", "")])
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={devices}"
-        out = subprocess.run(
-            [sys.executable, "-c", _CHILD,
-             str(n_queries), str(n_docs), str(reps)],
-            capture_output=True, text=True, env=env,
-            cwd=os.path.join(os.path.dirname(__file__), ".."))
-        if out.returncode != 0:
-            print(f"sharded devices={devices}: FAILED\n{out.stderr[-800:]}")
+    for n in DEVICE_COUNTS:
+        if n > len(devices):
+            print(f"sharded devices={n}: skipped ({len(devices)} visible)")
             continue
-        row = json.loads(out.stdout.strip().splitlines()[-1])
-        row.update(n_queries=n_queries, n_docs=n_docs)
-        if row["devices"] == 1:  # only the true 1-device run seeds the base
-            base_us = row["sharded_us"]
-        row["speedup_vs_1dev"] = (base_us / row["sharded_us"]
-                                  if base_us is not None else None)
+        sev = ShardedEvaluator(ev, mesh=Mesh(devices[:n], ("data",)))
+        us = time_call(lambda: sev.evaluate_buffer(buf), reps=reps) * 1e6
+        if n == 1:
+            base_us = us
+        row = {"devices": n, "sharded_us": us, "n_queries": n_queries,
+               "n_docs": n_docs, "platform": devices[0].platform,
+               "device_kind": devices[0].device_kind,
+               "speedup_vs_1dev": base_us / us}
         rows.append(row)
-        rel = (f"({row['speedup_vs_1dev']:.2f}x vs 1 device)"
-               if base_us is not None else "(1-device baseline missing)")
-        print(f"sharded devices={devices}: {row['sharded_us']:.0f}us {rel}")
+        print(f"sharded devices={n}: {us:.0f}us "
+              f"({row['speedup_vs_1dev']:.2f}x vs 1 device, "
+              f"{row['platform']})")
     return rows

@@ -10,12 +10,12 @@ Segments (repeat ``--only`` to pick several):
   loop vs the vectorized flat pipeline (cold dict ingest) vs the
   pre-tokenized session path (``batch_from_buffer`` on a ``RunBuffer``).
 * ``kernels``   — kernel-layer roofline: fused-measures achieved vs peak
-  bytes/s, execution mode (``ops.INTERPRET``), autotuned ``block_q``, and
-  the compile-count accounting behind shape bucketing; see
+  bytes/s, execution mode (``ops.interpret_mode()``), autotuned
+  ``block_q``, and the compile-count accounting behind shape bucketing; see
   ``bench_kernels``.
 * ``sharded``   — multi-device scaling of the sharded evaluation pipeline
-  (``repro.distributed.sharded_evaluator``) over 1/2/4/8 host-platform
-  devices; subprocess-per-device-count, see ``bench_sharded``.
+  (``repro.distributed.sharded_evaluator``) over meshes of the first
+  1/2/4/8 visible devices, in this process; see ``bench_sharded``.
 * ``serve``     — sustained throughput/latency of the async evaluation
   service (``repro.serve``) at several client-concurrency levels, including
   the request-coalescing factor; see ``bench_serve``.
@@ -26,6 +26,8 @@ Segments (repeat ``--only`` to pick several):
   same multi-collection workload through one in-process server vs the
   consistent-hash router at 1/2/4 workers (8 under ``--full``), with
   ``speedup_vs_single`` and the host core count; see ``bench_cluster``.
+  CPU hosts only: on a TPU host this process would hold the chips the
+  workers need, so this script refuses the segment there.
 * ``qlearning`` — the paper's RL demo, episodes/s.
 * ``batched``   — dense batched evaluation vs the dict API.
 * ``sweep``     — K-run sweep evaluation (``evaluate_sweep``) vs K
@@ -85,6 +87,18 @@ def main(argv=None) -> None:
             print(name)
         return
 
+    from repro import runtime
+
+    selected = args.only or list(SEGMENTS)
+    if "cluster" in selected and runtime.tpu_chips():
+        # Every segment runs in this process, which takes the chips at its
+        # first device call; the cluster's worker processes need them too.
+        ap.error("the cluster segment cannot run on a TPU host: this "
+                 "process (its in-process baseline server and every other "
+                 "segment) holds the chips its worker processes need; run "
+                 "it on a CPU host or leave it out with --only")
+    runtime.enable_compile_cache()
+
     from benchmarks import bench_batched, bench_client, bench_cluster, \
         bench_kernels, bench_qlearning, bench_rq1, bench_rq2, bench_serve, \
         bench_sharded, bench_sweep
@@ -100,7 +114,6 @@ def main(argv=None) -> None:
     for name, ref in SEGMENTS.items():
         mod, fn = ref.split(".")
         suites[name] = getattr(modules[mod], fn)
-    selected = args.only or list(suites)
     results = {}
     for name in selected:
         print(f"=== {name} ===", flush=True)

@@ -44,6 +44,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro import runtime
 from repro.core import (RelevanceEvaluator, measures as M, registry,
                         supported_measures, trec)
 
@@ -162,6 +163,7 @@ def main(argv: Optional[Sequence[str]] = None, out=None) -> int:
                     help="evaluate with the multi-device sharded pipeline")
     args = ap.parse_args(argv)
     out = out or sys.stdout
+    runtime.enable_compile_cache()
 
     selected = resolve_measures(args.measures)
     try:

@@ -221,17 +221,39 @@ def bpref(s: SortedBatch) -> jax.Array:
     return _safe_div(total, s.n_rel)
 
 
+def discount_table(d: int) -> np.ndarray:
+    """trec_eval's rank discounts ``1/log2(rank+1)`` for ranks ``1..d``.
+
+    Built on the host in float64 and rounded once to float32, so every
+    backend sees the same correctly rounded table: the TPU's float32
+    ``log2`` is an approximation (relative error up to ~6e-5 on v5e),
+    which moved nDCG by ~1e-5.
+    """
+    return (1.0 / np.log2(np.arange(2, d + 2, dtype=np.float64))).astype(
+        np.float32)
+
+
+def rbp_table(d: int, p: float) -> np.ndarray:
+    """RBP rank weights ``(1-p)·p^(rank-1)`` for ranks ``1..d`` (host-built,
+    like :func:`discount_table`, because the TPU's float32 ``pow`` is an
+    approximation)."""
+    return ((1.0 - p) * np.power(p, np.arange(d, dtype=np.float64))).astype(
+        np.float32)
+
+
 def _discounts(d: int) -> jax.Array:
-    return 1.0 / jnp.log2(_ranks(d) + 1.0)
+    return jnp.asarray(discount_table(d))
 
 
 def dcg(s: SortedBatch, k: int | None = None) -> jax.Array:
     """trec_eval DCG: linear gain rel / log2(rank + 1)."""
     d = s.rel.shape[-1]
-    disc = _discounts(d)
-    gains = jnp.maximum(s.rel, 0.0) * disc  # trec_eval: negative rels gain 0
+    gains = jnp.maximum(s.rel, 0.0) * _discounts(d)  # negative rels gain 0
     if k is not None:
-        gains = gains * (_ranks(d) <= k).astype(jnp.float32)
+        # Static slice to the cutoff BEFORE reducing (as in err_at): the
+        # reduction width is min(k, d) whatever the padding, so the top-k
+        # path (d == k) and the full-sort path sum in the same order.
+        gains = gains[:, :min(int(k), d)]
     return jnp.sum(gains, axis=-1)
 
 
@@ -298,8 +320,7 @@ def rbp(s: SortedBatch, p: float) -> jax.Array:
     persistence ``p``.  Documents beyond the retrieved depth contribute 0,
     i.e. this is the base RBP score without the residual.
     """
-    d = s.binrel.shape[-1]
-    weights = (1.0 - p) * jnp.power(p, _ranks(d) - 1.0)
+    weights = jnp.asarray(rbp_table(s.binrel.shape[-1], p))
     return jnp.sum(s.binrel * weights, axis=-1)
 
 

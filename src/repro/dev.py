@@ -131,7 +131,22 @@ _CLIENT_SMOKE = """
     payload = json.dumps({"op": "register_qrel", "qrel_id": "big",
                           "qrel": big_qrel})
     assert len(payload) > (1 << 16), len(payload)
+
+    # stdio first: a private subprocess server, same >64 KiB payload.  This
+    # process stays off JAX until the child has exited — on a TPU host a
+    # parent that holds the chip would leave the child none.
+    with EvalClient.spawn_stdio(
+            [sys.executable, "-m", "repro.serve", "--qrel", qrel_path,
+             "-m", "map", "--window-ms", "1"]) as client:
+        assert client.ping() == "pong"
+        r = client.evaluate("default",
+                            run={"q1": {"APPLE": 2.0, "BANANA": 1.0}})
+        assert r.per_query["q1"]["map"] > 0
+        client.register_qrel("big", big_qrel, ("map", "ndcg"))
+        stdio_res = client.evaluate("big", run=big_run)
+
     want = RelevanceEvaluator(big_qrel, ("map", "ndcg")).evaluate(big_run)
+    assert stdio_res.per_query == want  # bit-identical through stdio pipes
 
     # TCP: persistent connection, pipelining, >64 KiB payload
     with ServerThread(service_kw=dict(window=0.02)) as srv:
@@ -144,18 +159,6 @@ _CLIENT_SMOKE = """
             assert all(m.per_query == want for m in many)
         stats = srv.stats()
         assert stats["backend_calls"] < stats["requests"], stats
-
-    # stdio: a private subprocess server, same >64 KiB payload
-    with EvalClient.spawn_stdio(
-            [sys.executable, "-m", "repro.serve", "--qrel", qrel_path,
-             "-m", "map", "--window-ms", "1"]) as client:
-        assert client.ping() == "pong"
-        r = client.evaluate("default",
-                            run={"q1": {"APPLE": 2.0, "BANANA": 1.0}})
-        assert r.per_query["q1"]["map"] > 0
-        client.register_qrel("big", big_qrel, ("map", "ndcg"))
-        res = client.evaluate("big", run=big_run)
-        assert res.per_query == want  # and through stdio pipes
 
     print("client smoke: OK (TCP pipelined + stdio, >64 KiB payloads, "
           f"{stats['requests']} reqs -> {stats['backend_calls']} backend "
@@ -179,9 +182,10 @@ _CLUSTER_SMOKE = """
     payload = json.dumps({"op": "evaluate", "qrel_id": "big",
                           "run": big_run})
     assert len(payload) > (1 << 16), len(payload)
-    want = RelevanceEvaluator(big_qrel, ("map", "ndcg")).evaluate(big_run)
 
-    # a wide coalescing window so the kill lands mid-request
+    # a wide coalescing window so the kill lands mid-request.  This process
+    # stays off JAX while the workers run (they hold the chips on a TPU
+    # host); the reference answer is computed after they exit.
     with ClusterThread(2, worker_args=["--backend", "single",
                                        "--window-ms", "250"],
                        router_kw=dict(retries=4,
@@ -191,8 +195,7 @@ _CLUSTER_SMOKE = """
             health = client.health()
             assert health["status"] == "ok" and health["ready"] == 2, health
             client.register_qrel("big", big_qrel, ("map", "ndcg"))
-            res = client.evaluate("big", run=big_run)
-            assert res.per_query == want  # >64 KiB round trip, bit-identical
+            res = client.evaluate("big", run=big_run)  # >64 KiB round trip
 
             owner = cluster.owner_of("big")
             future = client.submit("big", run=big_run)
@@ -207,8 +210,11 @@ _CLUSTER_SMOKE = """
 
             cluster.call(wait_inflight(), timeout=60)
             cluster.kill_worker(owner)  # SIGKILL mid-request
-            assert future.result(180).per_query == want  # transparent retry
+            retried = future.result(180)  # transparent retry
         counters = dict(cluster.router.counters)
+    want = RelevanceEvaluator(big_qrel, ("map", "ndcg")).evaluate(big_run)
+    assert res.per_query == want  # bit-identical
+    assert retried.per_query == want
     assert counters["restarts"] >= 1 and counters["worker_retries"] >= 1, \\
         counters
     print("cluster smoke: OK (2 workers, >64 KiB through the router, "

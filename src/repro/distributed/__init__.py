@@ -1,25 +1,14 @@
 """Distribution layer: sharding rules, collective helpers, compression.
 
-Exports :func:`shard_map`, a version-compat shim over the moving JAX API:
-newer releases expose ``jax.shard_map`` (with ``check_vma``), older ones only
-``jax.experimental.shard_map.shard_map`` (with ``check_rep``).  All repro code
-must import shard_map from here rather than from jax directly.
+Exports :func:`shard_map` (``jax.shard_map``, with ``check_vma``), so repro
+code has one import site for it.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pre-0.6 JAX: experimental API, `check_rep` instead of `check_vma`
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _experimental_shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+shard_map = jax.shard_map
 
 
 __all__ = ["shard_map", "ShardedEvaluator", "ShardedResult",

@@ -102,9 +102,9 @@ class ShardedEvaluator:
     comparable to its single-device ``evaluate``.
 
     ``interpret`` forwards to the Pallas kernel.  The default SNAPSHOTS the
-    module-wide ``kernels.ops.INTERPRET`` (backend-resolved: compiled on
-    TPU, interpret elsewhere) at *construction* time — the value is baked
-    into the compiled dispatch closure, so flipping ``ops.INTERPRET``
+    module-wide default (``kernels.ops.interpret_mode()``, backend-resolved:
+    compiled on TPU, interpret elsewhere) at *construction* time — the value
+    is baked into the compiled dispatch closure, so flipping ``ops.INTERPRET``
     afterwards does not affect an existing instance.  Build a new
     ``ShardedEvaluator`` (or pass ``interpret=`` explicitly) to change
     mode; see the ``kernels.ops`` docstring for the full precedence rules.
@@ -118,7 +118,8 @@ class ShardedEvaluator:
                 f"need a 1-D query mesh, got axes {self.mesh.axis_names}")
         self.axis_name = self.mesh.axis_names[0]
         self.n_shards = int(np.prod(self.mesh.devices.shape))
-        self.interpret = ops.INTERPRET if interpret is None else interpret
+        self.interpret = (ops.interpret_mode() if interpret is None
+                          else interpret)
         self.keys: Tuple[str, ...] = tuple(evaluator.measure_keys)
         # Measures the fused kernel does not emit ride the reference core.
         self._rest = tuple(k for k in self.keys if k not in ops.FUSED_COLUMNS)

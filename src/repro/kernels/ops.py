@@ -13,8 +13,12 @@ Execution-mode (``interpret``) resolution, in priority order:
    compiled dispatch closure — keep their captured mode for their
    lifetime; rebuild them after flipping (``tests/test_kernels.py``
    pins both behaviours);
-3. :data:`INTERPRET` itself is resolved once at import by
-   :func:`resolve_interpret`: the ``REPRO_INTERPRET`` environment variable
+3. :data:`INTERPRET` starts unresolved (``None``) and is resolved once, at
+   the first kernel call (:func:`interpret_mode`), by
+   :func:`resolve_interpret` — never at import, because asking for the
+   backend initializes it, and a process that initializes the TPU backend
+   holds the chip (a router that imports this module must leave the chips
+   to its workers).  The ``REPRO_INTERPRET`` environment variable
    wins when set (``1/true/yes/on/interpret`` → interpret,
    ``0/false/no/off/compiled`` → compiled), otherwise the JAX backend
    decides — **compiled (``False``) on TPU**, interpret everywhere else
@@ -80,23 +84,33 @@ def resolve_interpret(env: Optional[str] = None,
     return backend != "tpu"
 
 
-#: process-wide default execution mode, backend-resolved at import (see
-#: the module docstring for the full precedence rules)
-INTERPRET = resolve_interpret()
+#: process-wide default execution mode; ``None`` until the first kernel call
+#: resolves it (see the module docstring for the full precedence rules)
+INTERPRET: Optional[bool] = None
+
+
+def interpret_mode() -> bool:
+    """The default execution mode, resolved on first use."""
+    global INTERPRET
+    if INTERPRET is None:
+        INTERPRET = resolve_interpret()
+    return INTERPRET
+
 
 FUSED_COLUMNS: Tuple[str, ...] = tuple(_fm.COLUMNS)
 
 
 def topk(scores, k, block_d=None, interpret=None):
     return _topk.topk(scores, k, block_d=block_d,
-                      interpret=INTERPRET if interpret is None else interpret)
+                      interpret=(interpret_mode() if interpret is None
+                                 else interpret))
 
 
 def embedding_bag(table, indices, segment_ids, n_bags, weights=None,
                   interpret=None):
     return _eb.embedding_bag(
         table, indices, segment_ids, n_bags, weights=weights,
-        interpret=INTERPRET if interpret is None else interpret)
+        interpret=interpret_mode() if interpret is None else interpret)
 
 
 def fused_measures_cols(rel_sorted, judged_sorted, scalars,
@@ -105,7 +119,7 @@ def fused_measures_cols(rel_sorted, judged_sorted, scalars,
     return _fm.fused_measures(
         rel_sorted, judged_sorted, scalars, block_q=block_q,
         relevance_level=relevance_level,
-        interpret=INTERPRET if interpret is None else interpret)
+        interpret=interpret_mode() if interpret is None else interpret)
 
 
 def make_scalars(n_rel, n_judged_nonrel, ideal_rel):
@@ -113,8 +127,7 @@ def make_scalars(n_rel, n_judged_nonrel, ideal_rel):
     q = n_rel.shape[0]
     j = ideal_rel.shape[-1]
     ranks = jnp.arange(1, j + 1, dtype=jnp.float32)
-    disc = 1.0 / jnp.log2(ranks + 1.0)
-    gains = jnp.maximum(ideal_rel, 0.0) * disc
+    gains = jnp.maximum(ideal_rel, 0.0) * M.discount_table(j)
     idcg_full = jnp.sum(gains, axis=-1)
     scal = [n_rel, n_judged_nonrel, idcg_full]
     for k in _fm.CUTOFFS:

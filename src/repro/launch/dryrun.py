@@ -24,15 +24,6 @@ import time
 import traceback
 
 
-def _cost_analysis(compiled) -> dict:
-    """Version-compat: ``Compiled.cost_analysis()`` returns a dict on newer
-    JAX but a one-element list of dicts on older releases."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _mesh_for(name: str, devices_per_pod: int = 256):
     import jax
     import numpy as np
@@ -88,7 +79,7 @@ def run_cell(arch_name: str, shape_name: str, mesh_name: str,
             mem = compiled.memory_analysis()
             print(f"[{arch_name} × {shape_name} × {mesh_name}] "
                   f"memory_analysis: {mem}")
-            cost = _cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             print(f"[{arch_name} × {shape_name} × {mesh_name}] "
                   f"cost_analysis: flops={cost.get('flops', 0):.3e} "
                   f"bytes={cost.get('bytes accessed', 0):.3e}")
@@ -158,7 +149,7 @@ def run_scan_probe(arch_name: str, shape_name: str, mesh_name: str,
                     out_shardings=bundle.out_shardings,
                     donate_argnums=bundle.donate_argnums,
                 ).lower(*bundle.arg_specs).compile()
-                cost = _cost_analysis(compiled)
+                cost = compiled.cost_analysis()
                 coll = hlo_lib.collective_bytes(compiled.as_text())
                 costs[nl] = {
                     "flops": float(cost.get("flops", 0.0)),

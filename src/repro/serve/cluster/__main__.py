@@ -117,8 +117,12 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     args = ap.parse_args(argv)
     limit = max(1, int(args.max_frame_mb * 2**20))
 
-    async def run() -> None:
+    try:
         router = build_router(args, frame_limit=limit)
+    except ValueError as e:  # e.g. more workers than the host has chips
+        ap.error(str(e))
+
+    async def run() -> None:
         await router.start()
         server = await serve_protocol(
             router.handle, args.host, args.port, limit=limit,

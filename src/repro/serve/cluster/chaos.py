@@ -219,15 +219,18 @@ class FaultProxy:
                 await writer.drain()
 
     async def aclose(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()
         for t in list(self._tasks):
             t.cancel()
         if self._tasks:
             await asyncio.gather(*self._tasks, return_exceptions=True)
         self._tasks.clear()
+        if server is not None:
+            # returns once every proxied connection has closed (Python >=
+            # 3.12), which the cancelled pumps above bring about
+            await server.wait_closed()
 
 
 class ProxyManager:

@@ -78,6 +78,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Sequence, Set
 
+from repro import runtime
 from repro.client.errors import ServerError
 from repro.serve.cluster.breaker import CircuitBreaker
 from repro.serve.cluster.journal import RegistrationJournal
@@ -144,7 +145,9 @@ class Router:
     bounds transparent re-sends of idempotent requests across worker
     failures; ``ready_timeout`` bounds how long a request waits for ANY
     replica to come (back) up before giving up with
-    ``worker_unavailable``.  ``state_dir`` makes the registration journal
+    ``worker_unavailable``; ``start_timeout`` bounds one worker
+    generation's start-up (spawn to ready, opening its device included)
+    and the initial pool's.  ``state_dir`` makes the registration journal
     durable; ``breaker_failures`` / ``breaker_cooldown`` parameterize each
     worker's circuit breaker; ``hedge_fraction`` is the share of a
     ``deadline_ms`` budget that elapses before an idempotent request is
@@ -168,6 +171,14 @@ class Router:
                  rng_seed: Optional[int] = None, wrap_endpoint=None):
         if n_workers < 1:
             raise ValueError(f"need at least one worker, got {n_workers}")
+        #: TPU chips (device nodes) on this host; each worker holds one
+        #: (none: no chip binding)
+        self._chips = runtime.tpu_chips()
+        if self._chips and n_workers > len(self._chips):
+            raise ValueError(
+                f"{n_workers} workers need {n_workers} TPU chips, and this "
+                f"host has {len(self._chips)}: each worker process holds "
+                "one chip")
         if not 0.0 < hedge_fraction <= 1.0:
             raise ValueError(
                 f"hedge_fraction must be in (0, 1], got {hedge_fraction}")
@@ -213,11 +224,12 @@ class Router:
         self._next_slot += 1
         if name in self._slots:
             raise ValueError(f"worker {name!r} already exists")
+        chip = self._free_chip(name) if self._chips else None
         slot = _Slot(
             name,
             WorkerProcess(name, extra_args=self._worker_args,
                           frame_limit=self._frame_limit,
-                          wrap_endpoint=self._wrap_endpoint),
+                          wrap_endpoint=self._wrap_endpoint, chip=chip),
             CircuitBreaker(failures=self._breaker_failures,
                            cooldown=self._breaker_cooldown))
         self._slots[name] = slot
@@ -225,6 +237,15 @@ class Router:
         slot.supervisor = loop.create_task(self._supervise(slot))
         slot.health_task = loop.create_task(self._health_loop(slot))
         return slot
+
+    def _free_chip(self, name: str) -> str:
+        """The first chip (device node) no live worker holds."""
+        held = {s.proc.chip for s in self._slots.values()}
+        for chip in self._chips:
+            if chip not in held:
+                return chip
+        raise ValueError(f"no free TPU chip for worker {name!r}: all "
+                         f"{len(self._chips)} are held, one per worker")
 
     async def start(self) -> None:
         """Spawn the initial pool and wait until every worker is ready.
@@ -254,7 +275,9 @@ class Router:
         backoff = self._backoff
         while not self._closing:
             try:
-                await slot.proc.start(ready_timeout=self._ready_timeout)
+                # a generation's start-up includes opening its device,
+                # which takes seconds on a TPU: bounded by start_timeout
+                await slot.proc.start(ready_timeout=self._start_timeout)
                 await self._replay(slot)
             except asyncio.CancelledError:
                 raise
@@ -307,18 +330,22 @@ class Router:
             if self._closing or not slot.ready.is_set():
                 continue
             client = slot.proc.client
+            t0 = time.monotonic()
             try:
                 await asyncio.wait_for(client.health(),
                                        self._health_timeout)
             except asyncio.CancelledError:
                 raise
-            except Exception:
+            except Exception as exc:
                 if self._closing or not slot.ready.is_set():
                     continue
                 self.counters["health_failures"] += 1
                 slot.breaker.record_failure()
+                tail = list(slot.proc.last_stderr)[-3:]
                 print(f"[cluster] worker {slot.name} failed its health "
-                      "check; killing for restart", file=sys.stderr,
+                      f"check ({type(exc).__name__} after "
+                      f"{time.monotonic() - t0:.1f}s); killing for "
+                      f"restart; last stderr: {tail}", file=sys.stderr,
                       flush=True)
                 slot.ready.clear()
                 slot.proc.kill()

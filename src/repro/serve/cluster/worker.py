@@ -26,6 +26,7 @@ import sys
 from typing import Deque, List, Optional, Sequence, Tuple
 
 import repro
+from repro import runtime
 from repro.client.aio import AsyncEvalClient
 from repro.serve.wire import DEFAULT_FRAME_LIMIT
 
@@ -53,13 +54,20 @@ class WorkerProcess:
     port)`` called once per generation, after the banner is parsed and
     before the client connects — the chaos harness uses it to interpose a
     fault-injecting TCP proxy between router and worker.
+
+    ``chip`` (a device node from :func:`repro.runtime.tpu_chips`) binds
+    every generation to that one TPU chip of the host
+    (:func:`repro.runtime.chip_env`); ``None`` leaves the environment's
+    devices as they are (CPU hosts).
     """
 
     def __init__(self, name: str, *, extra_args: Sequence[str] = (),
                  python: str = sys.executable,
                  frame_limit: int = DEFAULT_FRAME_LIMIT,
-                 env: Optional[dict] = None, wrap_endpoint=None):
+                 env: Optional[dict] = None, wrap_endpoint=None,
+                 chip: Optional[str] = None):
         self.name = name
+        self.chip = chip
         self._extra = [str(a) for a in extra_args]
         self._python = python
         self._frame_limit = int(frame_limit) + 4096  # id-splice headroom
@@ -89,6 +97,8 @@ class WorkerProcess:
         env = dict(os.environ)
         if self._env:
             env.update(self._env)
+        if self.chip is not None:
+            env.update(runtime.chip_env(self.chip))
         env["PYTHONPATH"] = _SRC + os.pathsep + env.get("PYTHONPATH", "")
         return env
 

@@ -460,7 +460,7 @@ def build_service(args) -> EvaluationService:
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
-    from repro import cli
+    from repro import cli, runtime
 
     ap = argparse.ArgumentParser(
         prog="python -m repro.serve",
@@ -500,6 +500,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "(default: max(1, rate))")
     args = ap.parse_args(argv)
     limit = max(1, int(args.max_frame_mb * 2**20))
+    runtime.enable_compile_cache()
+    # Open the device before announcing readiness: backend start-up takes
+    # seconds on a TPU, and inside the first request it would stall the
+    # event loop past a cluster router's health probe.
+    import jax
+
+    jax.devices()
 
     async def run() -> None:
         service = build_service(args)

@@ -510,13 +510,15 @@ def test_router_drain_answers_inflight_and_refuses_new():
         await writer.drain()
         while router._inflight == 0:
             await asyncio.sleep(0.001)
-        server.close()
-        await server.wait_closed()
+        server.close()  # stops listening at once
         drain = asyncio.get_running_loop().create_task(router.drain())
         answered = json.loads(await reader.readline())
         await drain
         writer.close()
         await writer.wait_closed()
+        # wait_closed() returns only once every connection has closed
+        # (Python >= 3.12), so it comes after this test's own connection
+        await server.wait_closed()
         refused = None
         try:
             r2, w2 = await asyncio.open_connection("127.0.0.1", port)

@@ -34,6 +34,19 @@ def test_topk_ties_break_by_index(dtype):
     np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
 
 
+@pytest.mark.parametrize("q,d,k", [(13, 3000, 100), (9, 1500, 10),
+                                   (17, 700, 300)])
+def test_topk_multirow_blocks_ragged_q_with_ties(q, d, k):
+    """8-row blocks over a query count that is not a multiple of 8, with
+    heavy ties and -inf (masked) entries: indices equal lax.top_k's."""
+    scores = RNG.choice(np.array([0.0, 1.0, 2.0, -np.inf], np.float32),
+                        size=(q, d), p=(0.5, 0.3, 0.1, 0.1))
+    v, i = ops.topk(jnp.asarray(scores), k)
+    rv, ri = jax.lax.top_k(jnp.asarray(scores), k)
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ri))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+
+
 def test_topk_handles_short_rows():
     scores = jnp.asarray(RNG.standard_normal((2, 5)).astype(np.float32))
     v, i = ops.topk(scores, 8)
@@ -52,6 +65,24 @@ def test_fused_measures_matches_ref(q, d):
                             batch.ideal_rel)
     got = ops.fused_measures_cols(s.rel, s.judged, scal)
     want = ref.fused_measures_ref(s.rel, s.judged, scal)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("q,block_q", [(21, 8), (37, 16)])
+def test_fused_measures_multirow_blocks_ragged_q(q, block_q):
+    """Several grid steps of several rows each, plus a padded last block."""
+    d = 512
+    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
+    rel = jnp.asarray(RNG.integers(0, 3, (q, d)).astype(np.float32))
+    judged = jnp.asarray(RNG.random((q, d)) < 0.5)
+    batch = M.batch_from_dense(scores, rel, judged=judged)
+    s = M.sort_batch(batch)
+    scal = ops.make_scalars(batch.n_rel, batch.n_judged_nonrel,
+                            batch.ideal_rel)
+    got = ops.fused_measures_cols(s.rel, s.judged, scal, block_q=block_q)
+    want = ref.fused_measures_ref(s.rel, s.judged, scal)
+    assert got.shape == (q, 64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-4, rtol=2e-4)
 
@@ -125,8 +156,7 @@ def test_resolve_interpret_backend_fallback():
 
 
 def test_module_default_matches_this_host():
-    assert ops.INTERPRET == ops.resolve_interpret(
-        env=None) or "REPRO_INTERPRET" not in __import__("os").environ
+    assert ops.interpret_mode() == ops.resolve_interpret(env=None)
     # on this host the resolved default must be valid: interpret anywhere,
     # compiled only on TPU
     if jax.default_backend() != "tpu":
@@ -178,7 +208,7 @@ def test_sharded_evaluator_snapshots_interpret(monkeypatch):
     from repro.distributed.sharded_evaluator import ShardedEvaluator
 
     ev = RelevanceEvaluator({"q1": {"d1": 1}}, ("map",))
-    live = ops.INTERPRET
+    live = ops.interpret_mode()
     se = ShardedEvaluator(ev)
     assert se.interpret == live
     # flipping the global does NOT change an existing instance...
@@ -200,7 +230,7 @@ def test_sharded_evaluator_snapshots_interpret(monkeypatch):
 
 def _assert_mode_parity(got, want, what):
     got, want = np.asarray(got), np.asarray(want)
-    if ops.INTERPRET:
+    if ops.interpret_mode():
         np.testing.assert_array_equal(got, want, err_msg=what)
     else:
         np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6,
