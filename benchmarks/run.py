@@ -151,7 +151,7 @@ def main(argv=None) -> None:
         if row["segment"] == "fused_roofline":
             print(f"kernels_fused_q{row['n_queries']}_d{row['n_docs']},"
                   f"{row['us_per_call']:.1f},"
-                  f"bw_fraction={row['bw_fraction']:.6f}")
+                  f"block_q={row['block_q']}")
         else:
             print(f"kernels_bucketing_w{row['distinct_wave_sizes']},"
                   f"{1e6 * row['elapsed_s'] / row['distinct_wave_sizes']:.1f},"

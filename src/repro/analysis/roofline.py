@@ -49,7 +49,6 @@ def kernel_roofline(bytes_moved: float, seconds: float,
         "seconds": seconds,
         "achieved_bytes_per_s": achieved_bw,
         "peak_bytes_per_s": HBM_BW,
-        "bw_fraction": achieved_bw / HBM_BW,
         "achieved_flops_per_s": achieved_flops,
         "flops_fraction": achieved_flops / PEAK_FLOPS,
     }

@@ -59,8 +59,10 @@ import threading
 from itertools import chain, repeat
 from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Union
 
+import jax
 import numpy as np
 
+from repro import obs
 from repro.core import measures as M
 from repro.core import registry
 from repro.kernels import bucketing
@@ -318,20 +320,20 @@ class RelevanceEvaluator:
         >>> ev.evaluate({'q1': {'d1': 0.2, 'd2': 0.9}})['q1']['map']
         0.5
         """
-        qids = [q for q in run if q in self._qrel]
-        if not qids:
-            return {}
         out: Dict[str, Dict[str, float]] = {}
-        for lo in range(0, len(qids), self.chunk_queries):
-            chunk = qids[lo:lo + self.chunk_queries]
-            if self.densify_path == "reference":
-                batch, _ = self._densify(run, chunk)
-                self._emit(out, chunk, batch)
-            else:
-                buf = self._tokenize_chunk(run, chunk)
-                topk = self._route_topk(buf)
-                batch = self.batch_from_buffer(buf, topk_layout=topk)
-                self._emit(out, chunk, batch, topk=topk)
+        with obs.span("repro.evaluate"):
+            qids = [q for q in run if q in self._qrel]
+            for lo in range(0, len(qids), self.chunk_queries):
+                chunk = qids[lo:lo + self.chunk_queries]
+                with obs.span("repro.ingest"):
+                    if self.densify_path == "reference":
+                        batch, _ = self._densify(run, chunk)
+                        topk = False
+                    else:
+                        buf = self._tokenize_chunk(run, chunk)
+                        topk = self._route_topk(buf)
+                        batch = self.batch_from_buffer(buf, topk_layout=topk)
+                self._emit([out], [chunk], batch, topk)
         return out
 
     def evaluate_many(
@@ -550,12 +552,14 @@ class RelevanceEvaluator:
         >>> ev.evaluate_buffer(buf, scores=[0.1, 0.9])['q1']['recip_rank']
         0.5
         """
-        if not len(buf):
-            return {}
-        topk = self._route_topk(buf)
-        batch = self.batch_from_buffer(buf, scores, topk_layout=topk)
         out: Dict[str, Dict[str, float]] = {}
-        self._emit(out, buf.qids, batch, topk=topk)
+        with obs.span("repro.evaluate"):
+            if not len(buf):
+                return out
+            with obs.span("repro.ingest"):
+                topk = self._route_topk(buf)
+                batch = self.batch_from_buffer(buf, scores, topk_layout=topk)
+            self._emit([out], [buf.qids], batch, topk)
         return out
 
     def evaluate_buffers(
@@ -584,34 +588,22 @@ class RelevanceEvaluator:
         [1.0, 0.5]
         """
         bufs = list(bufs)
-        if scores_list is not None:
-            if len(scores_list) != len(bufs):
-                raise ValueError(
-                    f"{len(scores_list)} score sets for {len(bufs)} buffers")
-            bufs = [b if s is None else b.with_scores(s)
-                    for b, s in zip(bufs, scores_list)]
-        if not bufs:
-            return []
-        nonempty = [b for b in bufs if len(b)]
-        if not nonempty:
-            return [{} for _ in bufs]
-        big = concat_run_buffers(nonempty)
-        topk = self._route_topk(big)
-        batch = self.batch_from_buffer(big, topk_layout=topk)
-        compute = (M.compute_measures_topk_jit if topk
-                   else M.compute_measures_jit)
-        per_query = compute(batch, self.measures, self.relevance_level,
-                            self.judged_docs_only)
-        cols = {k: np.asarray(per_query[k])[:len(big.qids)].tolist()
-                for k in self.measure_keys}
-        results: List[Dict[str, Dict[str, float]]] = []
-        lo = 0
-        for buf in bufs:
-            out: Dict[str, Dict[str, float]] = {}
-            for i, qid in enumerate(buf.qids):
-                out[qid] = {k: cols[k][lo + i] for k in self.measure_keys}
-            lo += len(buf.qids)
-            results.append(out)
+        with obs.span("repro.evaluate"):
+            with obs.span("repro.ingest"):
+                if scores_list is not None:
+                    if len(scores_list) != len(bufs):
+                        raise ValueError(f"{len(scores_list)} score sets for "
+                                         f"{len(bufs)} buffers")
+                    bufs = [b if s is None else b.with_scores(s)
+                            for b, s in zip(bufs, scores_list)]
+                nonempty = [b for b in bufs if len(b)]
+                if nonempty:
+                    big = concat_run_buffers(nonempty)
+                    topk = self._route_topk(big)
+                    batch = self.batch_from_buffer(big, topk_layout=topk)
+            results: List[Dict[str, Dict[str, float]]] = [{} for _ in bufs]
+            if nonempty:
+                self._emit(results, [b.qids for b in bufs], batch, topk)
         return results
 
     def evaluate_sharded(self, run_or_buffer, mesh=None):
@@ -841,17 +833,34 @@ class RelevanceEvaluator:
 
     # -- output ---------------------------------------------------------------
 
-    def _emit(self, out: Dict[str, Dict[str, float]], qids: Sequence[str],
-              batch: M.EvalBatch, topk: bool = False) -> None:
+    def _emit(self, outs: Sequence[Dict[str, Dict[str, float]]],
+              groups: Sequence[Sequence[str]], batch: M.EvalBatch,
+              topk: bool) -> None:
+        """Measure ``batch`` and fill ``outs[i][qid]`` for each qid of
+        ``groups[i]``; the groups lie end to end on the batch's query axis.
+
+        The host→device copy and the measure core each end on the device
+        (``block_until_ready``), with or without a profiler session, so a
+        traced call runs the same schedule as an untraced one.
+        """
+        with obs.span("repro.transfer"):
+            batch = jax.block_until_ready(jax.device_put(batch))
         compute = (M.compute_measures_topk_jit if topk
                    else M.compute_measures_jit)
-        per_query = compute(batch, self.measures, self.relevance_level,
-                            self.judged_docs_only)
-        nq = len(qids)
-        cols = {k: np.asarray(per_query[k])[:nq].tolist()
-                for k in self.measure_keys}
-        for i, qid in enumerate(qids):
-            out[qid] = {k: cols[k][i] for k in self.measure_keys}
+        with obs.span("repro.compute"):
+            per_query = jax.block_until_ready(compute(
+                batch, self.measures, self.relevance_level,
+                self.judged_docs_only))
+        keys = self.measure_keys
+        nq = sum(len(qids) for qids in groups)
+        with obs.span("repro.fetch"):
+            cols = {k: np.asarray(per_query[k])[:nq].tolist() for k in keys}
+        with obs.span("repro.results"):
+            lo = 0
+            for out, qids in zip(outs, groups):
+                for i, qid in enumerate(qids, lo):
+                    out[qid] = {k: cols[k][i] for k in keys}
+                lo += len(qids)
 
 
 def aggregate_results(results: Dict[str, Dict[str, float]]) -> Dict[str, float]:

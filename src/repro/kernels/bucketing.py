@@ -127,10 +127,15 @@ def record_trace(name: str) -> None:
     Call from INSIDE a jit'd function body: the call executes at trace
     time only, so each increment corresponds to one new compiled
     signature entering that engine's jit cache.  Thread-safe (traces can
-    run on executor threads).
+    run on executor threads).  While a profiler session records, it also
+    leaves a zero-length ``repro.compile.<name>`` record (:mod:`repro.obs`)
+    under the span whose call caused the retrace.
     """
     with _lock:
         _counts[name] = _counts.get(name, 0) + 1
+    from repro import obs  # lazy: this module stays free of jax imports
+
+    obs.mark(f"repro.compile.{name}")
 
 
 def compile_count(name: Optional[str] = None) -> int:

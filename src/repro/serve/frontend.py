@@ -60,6 +60,7 @@ import signal
 import sys
 from typing import Optional, Sequence, Tuple
 
+from repro import obs
 from repro.serve.service import EvaluationService, ServeResult
 from repro.serve.wire import (DEFAULT_FRAME_LIMIT, OversizedFrame,
                               ProtocolError, TokenBucket, iter_frames)
@@ -223,7 +224,8 @@ async def _dispatch_request(service: EvaluationService, op: str,
 def _decode(line: str) -> Tuple[Optional[dict], Optional[dict]]:
     """Parse one request line → ``(request, None)`` or ``(None, error)``."""
     try:
-        req = json.loads(line)
+        with obs.span("repro.serve.decode"):
+            req = json.loads(line)
         if not isinstance(req, dict):
             raise ValueError("request must be a JSON object")
     except ValueError as exc:
@@ -231,12 +233,18 @@ def _decode(line: str) -> Tuple[Optional[dict], Optional[dict]]:
     return req, None
 
 
+def _encode(resp: dict) -> str:
+    """One response object → its JSON line (no newline)."""
+    with obs.span("repro.serve.encode"):
+        return json.dumps(resp)
+
+
 async def handle_line(service: EvaluationService, line: str) -> str:
     """One protocol line in, one JSON response line out."""
     req, err = _decode(line)
     if err is not None:
-        return json.dumps(err)
-    return json.dumps(await handle_request(service, req))
+        return _encode(err)
+    return _encode(await handle_request(service, req))
 
 
 def _oversized_error(frame: OversizedFrame) -> dict:
@@ -281,7 +289,7 @@ async def serve_protocol(handler, host: str = "127.0.0.1", port: int = 0,
 
         async def send(payload) -> None:
             body = (payload if isinstance(payload, bytes)
-                    else json.dumps(payload).encode())
+                    else _encode(payload).encode())
             try:
                 async with wlock:
                     writer.write(body + b"\n")
@@ -419,7 +427,7 @@ async def serve_stdio(service: EvaluationService, in_stream=None,
         # the limit is in BYTES, matching the TCP framing exactly
         nbytes = len(body) if body.isascii() else len(body.encode("utf-8"))
         if nbytes > limit:
-            await emit(json.dumps(_oversized_error(
+            await emit(_encode(_oversized_error(
                 OversizedFrame(nbytes, limit))))
             continue
         t = loop.create_task(one(line))

@@ -42,11 +42,19 @@ from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
 
 import numpy as np
 
+from repro import obs
 from repro.core import RelevanceEvaluator, aggregate_results
 from repro.core.evaluator import RunBuffer
 from repro.core.sweep import common_qids
 from repro.serve.batcher import MicroBatcher
 from repro.serve.cache import LRUCache
+
+
+def _backend_call(evaluate_buffers, bufs):
+    """One coalesced backend call, on the executor thread it runs on, so
+    its span nests the evaluator's own."""
+    with obs.span("repro.serve.flush"):
+        return evaluate_buffers(bufs)
 
 
 class ServeResult(NamedTuple):
@@ -410,12 +418,12 @@ class EvaluationService:
             self._stats["backend_calls"] += 1
             if col.backend == "sharded":
                 results = await asyncio.to_thread(
-                    col.sharded.evaluate_buffers, bufs)
+                    _backend_call, col.sharded.evaluate_buffers, bufs)
                 packed = [ServeResult(r.per_query, r.aggregates)
                           for r in results]
             else:
                 tables = await asyncio.to_thread(
-                    col.evaluator.evaluate_buffers, bufs)
+                    _backend_call, col.evaluator.evaluate_buffers, bufs)
                 packed = [ServeResult(pq, aggregate_results(pq))
                           for pq in tables]
             for i, res in zip(idxs, packed):

@@ -54,6 +54,7 @@ def test_doc_code_blocks_run(path):
     "repro.kernels.ops",
     "repro.kernels.bucketing",
     "repro.kernels.autotune",
+    "repro.obs",
     "repro.stats",
     "repro.stats.significance",
     "repro.stats.corrections",
