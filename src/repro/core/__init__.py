@@ -17,13 +17,14 @@ from repro.core.measures import (
     GM_MIN,
     SUPPORTED_MEASURES as supported_measures,
     EvalBatch,
+    FlatLayout,
     batch_from_dense,
-    batch_from_flat,
     compute_measures,
     compute_measures_jit,
     compute_measures_topk,
     compute_measures_topk_jit,
     finalize_aggregates,
+    flat_layout,
     measure_keys,
     parse_measures,
 )
@@ -38,7 +39,8 @@ __all__ = [
     "aggregate_results",
     "concat_run_buffers",
     "evaluate_sweep",
-    "batch_from_flat",
+    "flat_layout",
+    "FlatLayout",
     "supported_measures",
     "AGGREGATE_ONLY_MEASURES",
     "DEFAULT_CUTOFFS",

@@ -94,10 +94,10 @@ class RunBuffer:
     """
 
     __slots__ = ("qids", "gidx", "qidx", "col", "counts", "rel", "judged",
-                 "tiebreak", "scores")
+                 "tiebreak", "scores", "layout")
 
     def __init__(self, qids, gidx, qidx, col, counts, rel, judged, tiebreak,
-                 scores):
+                 scores, layout=None):
         self.qids: List[str] = qids  # chunk qids, evaluation order
         self.gidx = gidx  # [nq] i64 — evaluator-global query indices
         self.qidx = qidx  # [n] i64 — flat doc → chunk-local query index
@@ -107,6 +107,10 @@ class RunBuffer:
         self.judged = judged  # [n] bool — doc appears in the qrels
         self.tiebreak = tiebreak  # [n] i32 — docno desc-lex rank in query
         self.scores = scores  # [n] f32 or None — default scores
+        # [(key, core.measures.FlatLayout) or None]: the padded layout
+        # batch_from_buffer last built, shared by every buffer with_scores
+        # derives from this one
+        self.layout = [None] if layout is None else layout
 
     def __len__(self) -> int:
         return len(self.qids)
@@ -119,7 +123,7 @@ class RunBuffer:
                 f"expected {self.qidx.shape[0]} scores, got {scores.shape[0]}")
         return RunBuffer(self.qids, self.gidx, self.qidx, self.col,
                          self.counts, self.rel, self.judged, self.tiebreak,
-                         scores)
+                         scores, self.layout)
 
 
 def concat_run_buffers(bufs: Sequence[RunBuffer]) -> RunBuffer:
@@ -168,6 +172,8 @@ class RelevanceEvaluator:
     ``evaluate_buffer`` / ``evaluate_buffers`` concurrently (the serve layer
     relies on this to run backend calls on executor threads).  The one lazy
     mutation — the seed reference-densifier state — is built under a lock.
+    A buffer's cached padded layout needs none: threads that race on a
+    new buffer each build an identical layout, and the last one kept wins.
     """
 
     def __init__(
@@ -496,26 +502,40 @@ class RelevanceEvaluator:
         ``core.measures.compute_measures_topk`` requires; the layout is
         measure-invariant for the full-sort path (``tiebreak`` still rides
         along as its own field).
+
+        Only the scores slab is new on each call.  The rest of the batch
+        and the scores' destinations (:class:`core.measures.FlatLayout`)
+        depend on the buffer, this evaluator and the padding alone: they
+        are built on a buffer's first call, kept on it (and on every buffer
+        :meth:`RunBuffer.with_scores` derives from it), read-only, and
+        reused while the padding and layout asked for stay the same.
         """
         if scores is not None:
             buf = buf.with_scores(scores)
         if buf.scores is None:
             raise ValueError("buffer has no scores; pass scores=")
         nq = len(buf.qids)
+        key = (self, bucketing.bucket_queries(nq, multiple=q_multiple),
+               bool(topk_layout))
+        held = buf.layout[0]
+        if held is not None and held[0] == key:
+            obs.mark("repro.layout.hit")
+            return held[1].batch(buf.scores)
+        obs.mark("repro.layout.build")
         max_d = int(buf.counts.max()) if nq else 0
         jcounts = self._judged_counts[buf.gidx]
         max_j = int(jcounts.max()) if nq else 0
-        q_pad = bucketing.bucket_queries(nq, multiple=q_multiple)
-        return M.batch_from_flat(
+        layout = M.flat_layout(
             qidx=buf.qidx,
             col=buf.tiebreak if topk_layout else buf.col,
-            scores=buf.scores,
             tiebreak=buf.tiebreak, rel=buf.rel, judged=buf.judged,
             ideal_rows=self._ideal[buf.gidx],
             n_rel=self._n_rel[buf.gidx],
             n_judged_nonrel=self._n_nonrel[buf.gidx],
-            n_queries=nq, q_pad=q_pad, d_pad=_bucket(max_d),
+            n_queries=nq, q_pad=key[1], d_pad=_bucket(max_d),
             j_pad=_bucket(max(max_j, 1)), counts=buf.counts)
+        buf.layout[0] = (key, layout)
+        return layout.batch(buf.scores)
 
     def _route_topk(self, buf: RunBuffer) -> bool:
         """Should this buffer take the top-k kernel path?
