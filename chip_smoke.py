@@ -1,6 +1,6 @@
 """On-chip smoke test: the evaluator's user paths on a TPU, checked end to end.
 
-    python chip_smoke.py [--seed N]              # one chip: phases A-D
+    python chip_smoke.py [--seed N]              # one chip: phases A-D, G
     python chip_smoke.py --chips 4 [--seed N]    # four chips: E and F only
 
 Every collection is generated from ``--seed`` at a published shape, and
@@ -23,6 +23,10 @@ every phase checks its answers against the plain reference engine
   chip each; this process stays off JAX while they run.
 * **F · sharded mesh** (``--chips 4``): ``ShardedEvaluator`` over a 4-chip
   mesh against single-device evaluation of the same data.
+* **G · ragged graded lists** (learning-to-rank shape: a few hundred
+  queries, 1 to 1,251 documents each, every one judged 0-4) through
+  ``evaluate_buffer``, twice: the buffer is evaluated in depth classes,
+  the narrow ones on the XLA sort and the wide ones on the top-k kernel.
 
 Lines before the last report each phase's shapes, route, compile counts,
 largest difference from the reference and cold wall time (set-up time,
@@ -86,8 +90,19 @@ class DevSetShape:
     sample: int = 600  # queries checked against the reference
 
 
+@dataclass(frozen=True)
+class RaggedShape:
+    """Learning-to-rank lists (MSLR-WEB30K's kind): list lengths spread
+    log-uniformly over 1-1,251, every listed document judged 0-4."""
+
+    queries: int = 400
+    longest: int = 1251
+    grade_p: tuple = (0.52, 0.32, 0.13, 0.02, 0.01)
+
+
 ROBUST04 = AdHocShape()
 MSMARCO_DEV = DevSetShape()
+RAGGED = RaggedShape()
 SERVED_REQUESTS = 8
 
 MEASURES_A = ("map", "bpref", "ndcg", "Rprec", "recip_rank", "P", "recall",
@@ -96,6 +111,8 @@ MEASURES_A = ("map", "bpref", "ndcg", "Rprec", "recip_rank", "P", "recall",
 # these three are depth-bounded at 10, which routes to the top-k kernel.
 MEASURES_B = ("nDCG@10", "P@10", "Success@10")
 REFERENCE_B = ("ndcg_cut", "P", "success")
+MEASURES_G = ("nDCG@5", "nDCG@10")
+REFERENCE_G = ("ndcg_cut",)
 
 
 # -- collections --------------------------------------------------------------
@@ -155,6 +172,28 @@ def devset_collection(seed: int, shape: DevSetShape = None):
         qrel[str(qid_ids[q])] = {str(p): 1 for p in rels}
     qids = np.repeat(qid_ids.astype(str), depth)
     return qrel, qids, ret.reshape(-1).astype(str), scores.reshape(-1)
+
+
+def ragged_collection(seed: int, shape: RaggedShape = None):
+    """Qrels (dict) and a run (flat arrays) of lists of many lengths; the
+    list of a query is its judged set, scores on a 0.01 grid."""
+    shape = shape or RAGGED
+    rng = np.random.default_rng(seed)
+    lengths = np.exp(rng.uniform(0, np.log(shape.longest), shape.queries))
+    lengths = np.clip(np.rint(lengths), 1, shape.longest).astype(np.int64)
+    lengths[0] = shape.longest
+    qrel, qids, docnos, scores = {}, [], [], []
+    for q, n in enumerate(lengths.tolist()):
+        qid = f"{q + 1:04d}"
+        names = [f"D{j:04d}" for j in range(n)]
+        grades = rng.choice(5, n, p=shape.grade_p)
+        qrel[qid] = dict(zip(names, grades.tolist()))
+        qids.append(np.full(n, qid))
+        docnos.append(np.array(names))
+        scores.append(np.round(grades + rng.normal(size=n), 2)
+                      .astype(np.float32))
+    return (qrel, np.concatenate(qids), np.concatenate(docnos),
+            np.concatenate(scores))
 
 
 def run_dict(qids, docnos, scores):
@@ -406,6 +445,36 @@ def phase_d(coll, seed: int) -> None:
            max_abs_diff=f"{worst:.3g}", **fields)
 
 
+def phase_g(seed: int) -> None:
+    from repro.core import RelevanceEvaluator
+
+    qrel, qids, docnos, scores = ragged_collection(seed + 4)
+    rescored = np.round(scores + np.random.default_rng(seed + 5).normal(
+        scale=0.5, size=scores.shape), 2).astype(np.float32)
+    fields = {"shape": f"{len(qrel)} lists of 1-{RAGGED.longest}",
+              "rows": len(scores)}
+    with timed(fields):
+        ev = RelevanceEvaluator(qrel, MEASURES_G)
+        buf = ev.buffer_from_arrays(qids, docnos, scores)
+        got = [ev.evaluate_buffer(buf),
+               ev.evaluate_buffer(buf, scores=rescored)]
+    classes = buf.layout[0][1].classes
+    topk = [c.d_pad for c in classes if c.topk]
+    if len(classes) < 4 or not topk or len(topk) == len(classes):
+        raise SmokeFailure(
+            f"G: expected four or more depth classes on both routes, got "
+            f"{[(c.d_pad, c.topk) for c in classes]}")
+    worst = max(max_diff(g, pure_eval.evaluate(
+        run_dict(qids, docnos, s), qrel, REFERENCE_G), ev.measure_keys)
+        for g, s in zip(got, (scores, rescored)))
+    check("G (depth classes)", worst)
+    cells = sum(c.q_pad * c.d_pad for c in classes)
+    report("G ragged graded lists", route="depth_classes",
+           classes=len(classes), topk_classes=len(topk),
+           pad_share=f"{1 - len(scores) / cells:.4f}",
+           max_abs_diff=f"{worst:.3g}", **fields)
+
+
 def phase_e(coll, chip_ids) -> None:
     """Cluster: 4 worker processes, one chip each; this process stays off
     JAX until they have exited."""
@@ -518,7 +587,8 @@ def one_chip(seed: int):
 
     run_phases([("A", a), ("B", lambda: phase_b(seed)),
                 ("C", lambda: phase_c(coll)),
-                ("D", lambda: phase_d(coll, seed))])
+                ("D", lambda: phase_d(coll, seed)),
+                ("G", lambda: phase_g(seed))])
     return devices
 
 
@@ -543,7 +613,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0,
                     help="seed every generated collection (default 0)")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="1: phases A-D on one chip (default); 4: the "
+                    help="1: phases A-D and G on one chip (default); 4: the "
                          "cluster and the sharded mesh across four chips")
     args = ap.parse_args(argv)
     runtime.enable_compile_cache()

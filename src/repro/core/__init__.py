@@ -14,6 +14,7 @@ from repro.core.evaluator import (RelevanceEvaluator, RunBuffer,
 from repro.core.measures import (
     AGGREGATE_ONLY_MEASURES,
     DEFAULT_CUTOFFS,
+    DepthClass,
     GM_MIN,
     SUPPORTED_MEASURES as supported_measures,
     EvalBatch,
@@ -40,6 +41,7 @@ __all__ = [
     "concat_run_buffers",
     "evaluate_sweep",
     "flat_layout",
+    "DepthClass",
     "FlatLayout",
     "supported_measures",
     "AGGREGATE_ONLY_MEASURES",

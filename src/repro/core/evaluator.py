@@ -108,8 +108,8 @@ class RunBuffer:
         self.tiebreak = tiebreak  # [n] i32 — docno desc-lex rank in query
         self.scores = scores  # [n] f32 or None — default scores
         # [(key, core.measures.FlatLayout) or None]: the padded layout
-        # batch_from_buffer last built, shared by every buffer with_scores
-        # derives from this one
+        # (one rectangle, or the depth classes of an evaluation) last
+        # built, shared by every buffer with_scores derives from this one
         self.layout = [None] if layout is None else layout
 
     def __len__(self) -> int:
@@ -334,12 +334,16 @@ class RelevanceEvaluator:
                 with obs.span("repro.ingest"):
                     if self.densify_path == "reference":
                         batch, _ = self._densify(run, chunk)
-                        topk = False
+                        q_pad, d_pad = batch.mask.shape
+                        classes = [M.DepthClass(
+                            np.arange(len(chunk)), q_pad, d_pad,
+                            batch.ideal_rel.shape[1], False,
+                            int(np.count_nonzero(batch.mask)))]
+                        batches = [batch]
                     else:
-                        buf = self._tokenize_chunk(run, chunk)
-                        topk = self._route_topk(buf)
-                        batch = self.batch_from_buffer(buf, topk_layout=topk)
-                self._emit([out], [chunk], batch, topk)
+                        classes, batches = self._class_batches(
+                            self._tokenize_chunk(run, chunk))
+                self._emit([out], [chunk], classes, batches)
         return out
 
     def evaluate_many(
@@ -503,6 +507,10 @@ class RelevanceEvaluator:
         measure-invariant for the full-sort path (``tiebreak`` still rides
         along as its own field).
 
+        The batch is one rectangle, padded to the buffer's longest list:
+        the evaluator's own calls split a buffer into depth classes
+        instead (:meth:`_class_batches`).
+
         Only the scores slab is new on each call.  The rest of the batch
         and the scores' destinations (:class:`core.measures.FlatLayout`)
         depend on the buffer, this evaluator and the padding alone: they
@@ -515,30 +523,74 @@ class RelevanceEvaluator:
         if buf.scores is None:
             raise ValueError("buffer has no scores; pass scores=")
         nq = len(buf.qids)
-        key = (self, bucketing.bucket_queries(nq, multiple=q_multiple),
-               bool(topk_layout))
+        q_pad = bucketing.bucket_queries(nq, multiple=q_multiple)
+        layout = self._layout(buf, (q_pad, bool(topk_layout)), lambda: [
+            self._depth_class(buf, np.arange(nq), q_pad, bool(topk_layout))])
+        return layout.batches(buf.scores)[0]
+
+    def _class_batches(self, buf: RunBuffer):
+        """``(classes, batches)``: one padded batch per depth class of
+        ``buf``, each routed on its own padded depth.
+
+        A query's class is the padding class of its own list length
+        (``bucketing.bucket_docs``), so a ragged buffer is not padded to
+        its longest list; a buffer whose lists share one class is one
+        batch, the one :meth:`batch_from_buffer` gives.  The split and each
+        class's static slabs are built once and kept on the buffer, like
+        :meth:`batch_from_buffer`'s layout.
+        """
+        if buf.scores is None:
+            raise ValueError("buffer has no scores; pass scores=")
+        layout = self._layout(buf, "depth classes",
+                              lambda: self._depth_classes(buf))
+        return layout.classes, layout.batches(buf.scores)
+
+    def _layout(self, buf: RunBuffer, key, classes) -> M.FlatLayout:
+        """The layout ``buf`` holds under ``key``, or one built from
+        ``classes()`` and kept on it in its place."""
+        key = (self, key)
         held = buf.layout[0]
         if held is not None and held[0] == key:
             obs.mark("repro.layout.hit")
-            return held[1].batch(buf.scores)
+            return held[1]
         obs.mark("repro.layout.build")
-        max_d = int(buf.counts.max()) if nq else 0
-        jcounts = self._judged_counts[buf.gidx]
-        max_j = int(jcounts.max()) if nq else 0
         layout = M.flat_layout(
-            qidx=buf.qidx,
-            col=buf.tiebreak if topk_layout else buf.col,
-            tiebreak=buf.tiebreak, rel=buf.rel, judged=buf.judged,
-            ideal_rows=self._ideal[buf.gidx],
+            qidx=buf.qidx, col=buf.col, tiebreak=buf.tiebreak, rel=buf.rel,
+            judged=buf.judged, ideal_rows=self._ideal[buf.gidx],
             n_rel=self._n_rel[buf.gidx],
-            n_judged_nonrel=self._n_nonrel[buf.gidx],
-            n_queries=nq, q_pad=key[1], d_pad=_bucket(max_d),
-            j_pad=_bucket(max(max_j, 1)), counts=buf.counts)
+            n_judged_nonrel=self._n_nonrel[buf.gidx], counts=buf.counts,
+            classes=classes())
         buf.layout[0] = (key, layout)
-        return layout.batch(buf.scores)
+        return layout
 
-    def _route_topk(self, buf: RunBuffer) -> bool:
-        """Should this buffer take the top-k kernel path?
+    def _depth_classes(self, buf: RunBuffer) -> List[M.DepthClass]:
+        """``buf``'s queries grouped by the padding class of their list
+        length, shallowest first; each class padded and routed alone."""
+        depths, of_query = np.unique(buf.counts, return_inverse=True)
+        pads = np.array([_bucket(int(d)) for d in depths.tolist()],
+                        dtype=np.int64)[of_query.reshape(-1)]
+        classes = []
+        for d_pad in np.unique(pads).tolist():
+            queries = np.flatnonzero(pads == d_pad)
+            classes.append(self._depth_class(
+                buf, queries, bucketing.bucket_queries(len(queries)),
+                self._route_topk(d_pad)))
+        return classes
+
+    def _depth_class(self, buf: RunBuffer, queries: np.ndarray, q_pad: int,
+                     topk: bool) -> M.DepthClass:
+        """``queries`` of ``buf`` as one class, padded to their longest
+        retrieved and judged lists."""
+        counts = buf.counts[queries]
+        judged = self._judged_counts[buf.gidx[queries]]
+        return M.DepthClass(
+            queries, q_pad, _bucket(int(counts.max(initial=0))),
+            _bucket(max(int(judged.max(initial=0)), 1)), topk,
+            int(counts.sum()))
+
+    def _route_topk(self, d_pad: int) -> bool:
+        """Should a batch padded to ``d_pad`` documents take the top-k
+        kernel path?
 
         Yes iff every requested measure is depth-bounded (ROADMAP item 2:
         ``*_cut`` / ``@k`` measures stop sorting the full document axis) and
@@ -546,11 +598,10 @@ class RelevanceEvaluator:
         prefix beats the full multi-key sort.  Results are bit-identical
         either way (parity-tested in tests/test_measures.py).
         """
-        if self._topk_depth is None or not len(buf):
+        if self._topk_depth is None:
             return False
         from repro.kernels import topk as _tk
 
-        d_pad = _bucket(int(buf.counts.max()))
         k2 = _tk._next_pow2(self._topk_depth, 128)
         return d_pad > max(2 * k2, 512)
 
@@ -577,9 +628,10 @@ class RelevanceEvaluator:
             if not len(buf):
                 return out
             with obs.span("repro.ingest"):
-                topk = self._route_topk(buf)
-                batch = self.batch_from_buffer(buf, scores, topk_layout=topk)
-            self._emit([out], [buf.qids], batch, topk)
+                if scores is not None:
+                    buf = buf.with_scores(scores)
+                classes, batches = self._class_batches(buf)
+            self._emit([out], [buf.qids], classes, batches)
         return out
 
     def evaluate_buffers(
@@ -591,9 +643,10 @@ class RelevanceEvaluator:
 
         The coalescing hook for the serve layer
         (:mod:`repro.serve`): the buffers are stacked end to end on the query
-        axis (:func:`concat_run_buffers`), scattered into one padded
-        ``EvalBatch``, and dispatched to the jitted measure core once; the
-        per-query columns are then split back by each buffer's query count.
+        axis (:func:`concat_run_buffers`), scattered into the padded
+        ``EvalBatch`` of each depth class, and dispatched to the jitted
+        measure core once per class; the per-query columns are then split
+        back by each buffer's query count.
         Results are bit-identical to calling :meth:`evaluate_buffer` once per
         buffer — measures are computed row-independently, so stacking the
         query axis (like sharding it) cannot change any value.
@@ -618,12 +671,11 @@ class RelevanceEvaluator:
                             for b, s in zip(bufs, scores_list)]
                 nonempty = [b for b in bufs if len(b)]
                 if nonempty:
-                    big = concat_run_buffers(nonempty)
-                    topk = self._route_topk(big)
-                    batch = self.batch_from_buffer(big, topk_layout=topk)
+                    classes, batches = self._class_batches(
+                        concat_run_buffers(nonempty))
             results: List[Dict[str, Dict[str, float]]] = [{} for _ in bufs]
             if nonempty:
-                self._emit(results, [b.qids for b in bufs], batch, topk)
+                self._emit(results, [b.qids for b in bufs], classes, batches)
         return results
 
     def evaluate_sharded(self, run_or_buffer, mesh=None):
@@ -854,33 +906,55 @@ class RelevanceEvaluator:
     # -- output ---------------------------------------------------------------
 
     def _emit(self, outs: Sequence[Dict[str, Dict[str, float]]],
-              groups: Sequence[Sequence[str]], batch: M.EvalBatch,
-              topk: bool) -> None:
-        """Measure ``batch`` and fill ``outs[i][qid]`` for each qid of
-        ``groups[i]``; the groups lie end to end on the batch's query axis.
+              groups: Sequence[Sequence[str]],
+              classes: Sequence[M.DepthClass],
+              batches: Sequence[M.EvalBatch]) -> None:
+        """Measure each class's batch and fill ``outs[i][qid]`` for each
+        qid of ``groups[i]``; the groups lie end to end on the query axis
+        that the classes partition, and come out in that order.
 
-        The host→device copy and the measure core each end on the device
-        (``block_until_ready``), with or without a profiler session, so a
-        traced call runs the same schedule as an untraced one.
+        Every batch goes to the device in one copy, and every class's
+        measure core is launched before one wait.  The copy and the measure
+        cores each end on the device (``block_until_ready``), with or
+        without a profiler session, so a traced call runs the same schedule
+        as an untraced one.
         """
         with obs.span("repro.transfer"):
-            batch = jax.block_until_ready(jax.device_put(batch))
-        compute = (M.compute_measures_topk_jit if topk
-                   else M.compute_measures_jit)
+            for c in classes:
+                obs.count("repro.batch.rows", c.docs)
+                obs.count("repro.batch.cells", c.q_pad * c.d_pad)
+            batches = jax.block_until_ready(jax.device_put(list(batches)))
         with obs.span("repro.compute"):
-            per_query = jax.block_until_ready(compute(
-                batch, self.measures, self.relevance_level,
-                self.judged_docs_only))
+            per_class = jax.block_until_ready([
+                (M.compute_measures_topk_jit if c.topk
+                 else M.compute_measures_jit)(
+                    batch, self.measures, self.relevance_level,
+                    self.judged_docs_only)
+                for c, batch in zip(classes, batches)])
         keys = self.measure_keys
         nq = sum(len(qids) for qids in groups)
         with obs.span("repro.fetch"):
-            cols = {k: np.asarray(per_query[k])[:nq].tolist() for k in keys}
+            if len(classes) == 1:
+                cols = {k: np.asarray(per_class[0][k])[:nq].tolist()
+                        for k in keys}
+            else:
+                cols = {k: _stitch(classes, [pq[k] for pq in per_class], nq)
+                        for k in keys}
         with obs.span("repro.results"):
             lo = 0
             for out, qids in zip(outs, groups):
                 for i, qid in enumerate(qids, lo):
                     out[qid] = {k: cols[k][i] for k in keys}
                 lo += len(qids)
+
+
+def _stitch(classes: Sequence[M.DepthClass], columns, nq: int) -> list:
+    """One measure's per-class columns back in the buffer's query order."""
+    parts = [np.asarray(column) for column in columns]
+    out = np.empty(nq, dtype=parts[0].dtype)
+    for c, part in zip(classes, parts):
+        out[c.queries] = part[:len(c.queries)]
+    return out.tolist()
 
 
 def aggregate_results(results: Dict[str, Dict[str, float]]) -> Dict[str, float]:

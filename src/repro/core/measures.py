@@ -26,7 +26,7 @@ are excluded by the aggregation helpers.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -495,31 +495,58 @@ def finalize_aggregates(aggs: Dict[str, float]) -> Dict[str, float]:
 # ---------------------------------------------------------------------------
 
 
-class FlatLayout(NamedTuple):
-    """Where a flat run's documents land in a padded ``EvalBatch``, with
-    every field of the batch that does not depend on the scores.
+class DepthClass(NamedTuple):
+    """One padded batch of a :class:`FlatLayout`: its queries and padding.
 
-    Built once by :func:`flat_layout`; :meth:`batch` then places one set of
-    flat scores per call.  ``dest`` is each document's position in the
-    raveled ``[q_pad, d_pad]`` slab, or ``None`` where the flat order is
-    the query-major ``rows × depth`` block and the scores are one reshape
-    copy.  The static slabs are read-only: every batch shares them.
+    ``RelevanceEvaluator`` groups a buffer's queries by the padding class
+    of their own list length (``kernels.bucketing.bucket_docs``), so each
+    group is padded to its own ``[q_pad, d_pad]`` rectangle rather than to
+    the buffer's longest list.  A buffer whose lists share one class is one
+    class: its whole query axis.
     """
 
-    static: EvalBatch  # scores is None
+    queries: np.ndarray  # [n] intp — the buffer's query indices, ascending
+    q_pad: int
+    d_pad: int
+    j_pad: int  # ideal-gain columns: the class's longest judged list
+    topk: bool  # each document at column == its tiebreak rank (top-k layout)
+    docs: int  # real documents in the class
+
+
+class FlatLayout(NamedTuple):
+    """Where a flat run's documents land in the padded ``EvalBatch`` of
+    each depth class, with every field of those batches that does not
+    depend on the scores.
+
+    Built once by :func:`flat_layout`; :meth:`batches` then places one set
+    of flat scores per call.  The classes' scores slabs lie end to end in
+    one fresh array; ``dest`` is each document's position in it, or
+    ``None`` where there is one class and the flat order is its query-major
+    ``rows × depth`` block, so the scores are one reshape copy.  The static
+    slabs are read-only: every batch shares them.
+    """
+
+    classes: Tuple[DepthClass, ...]
+    static: Tuple[EvalBatch, ...]  # one per class; scores is None
     dest: np.ndarray | None  # [n] intp
     rows: int
     depth: int
 
-    def batch(self, scores: np.ndarray) -> EvalBatch:
-        """The padded batch of these flat scores, in a fresh slab."""
-        slab = np.zeros(self.static.mask.shape, dtype=np.float32)
+    def batches(self, scores: np.ndarray) -> List[EvalBatch]:
+        """The padded batch of each class for these flat scores."""
+        shapes = [(c.q_pad, c.d_pad) for c in self.classes]
+        flat = np.zeros(sum(q * d for q, d in shapes), dtype=np.float32)
         if self.dest is None:
-            slab[:self.rows, :self.depth] = scores.reshape(self.rows,
-                                                           self.depth)
+            flat.reshape(shapes[0])[:self.rows, :self.depth] = scores.reshape(
+                self.rows, self.depth)
         else:
-            slab.reshape(-1)[self.dest] = scores
-        return self.static._replace(scores=slab)
+            flat[self.dest] = scores
+        out, lo = [], 0
+        for static, (q, d) in zip(self.static, shapes):
+            out.append(static._replace(
+                scores=flat[lo:lo + q * d].reshape(q, d)))
+            lo += q * d
+        return out
 
 
 def flat_layout(
@@ -532,70 +559,95 @@ def flat_layout(
     ideal_rows: np.ndarray,
     n_rel: np.ndarray,
     n_judged_nonrel: np.ndarray,
-    n_queries: int,
-    q_pad: int,
-    d_pad: int,
-    j_pad: int,
     counts: np.ndarray,
+    classes: Sequence[DepthClass],
 ) -> FlatLayout:
-    """Scatter the score-independent flat arrays into padded slabs.
+    """Scatter the score-independent flat arrays into each class's slabs.
 
     The host-side counterpart of :func:`batch_from_dense`; the layout's
-    :meth:`FlatLayout.batch` completes the ``EvalBatch`` with each set of
-    flat scores.  All per-document vectors are flat (concatenated in query
-    order), with ``(qidx, col)`` giving each document's position in the
-    padded ``[q_pad, d_pad]`` tensors and ``counts`` each query's
-    documents.  When every query retrieved the same depth and ``(qidx,
-    col)`` is the query-major order (the fixed-depth case that dominates
-    real runs and the RQ1 grid), each field is a reshape copy; otherwise
-    one 1-D scatter through the flat destination index per field.  The
-    validity mask is a broadcast compare either way.
+    :meth:`FlatLayout.batches` completes each class's ``EvalBatch`` with
+    each set of flat scores.  All per-document vectors are flat
+    (concatenated in query order), with ``qidx`` each document's query,
+    ``col`` its column (``tiebreak`` in a ``topk`` class), ``counts`` each
+    query's documents and the per-query rows indexed by query.  ``classes``
+    partition the queries.  When there is one class, every query retrieved
+    the same depth and ``(qidx, col)`` is the query-major order (the
+    fixed-depth case that dominates real runs and the RQ1 grid), each field
+    is a reshape copy; otherwise one 1-D scatter through the flat
+    destination index per field, into the classes' slabs laid end to end.
+    The validity mask is a broadcast compare either way.
     """
-    tiebreak2 = np.zeros((q_pad, d_pad), dtype=np.int32)
-    rel2 = np.zeros((q_pad, d_pad), dtype=np.float32)
-    judged2 = np.zeros((q_pad, d_pad), dtype=bool)
-    mask2 = np.zeros((q_pad, d_pad), dtype=bool)
-    mask2[:n_queries] = (np.arange(d_pad, dtype=np.int64)[None, :]
-                         < counts[:, None])
+    classes = tuple(classes)
+    sizes = [c.q_pad * c.d_pad for c in classes]
+    tiebreak_all = np.zeros(sum(sizes), dtype=np.int32)
+    rel_all = np.zeros(sum(sizes), dtype=np.float32)
+    judged_all = np.zeros(sum(sizes), dtype=bool)
+    mask_all = np.zeros(sum(sizes), dtype=bool)
+    n_queries = counts.shape[0]
     d = int(counts[0]) if n_queries else 0
     grid = (n_queries, d)
-    # the reshape copy assumes query-major flat order; verify that
-    # (qidx, col) really is the implied layout rather than trusting it
-    uniform = (d and int(counts.min()) == d == int(counts.max())
-               and qidx.shape[0] == n_queries * d
-               and bool((col.reshape(grid) == np.arange(d)).all())
-               and bool((qidx.reshape(grid)
-                         == np.arange(n_queries)[:, None]).all()))
-    if uniform:
-        dest = None
-        tiebreak2[:n_queries, :d] = tiebreak.reshape(grid)
-        rel2[:n_queries, :d] = rel.reshape(grid)
-        judged2[:n_queries, :d] = judged.reshape(grid)
+    if len(classes) == 1:
+        only = classes[0]
+        cols = tiebreak if only.topk else col
+        # the reshape copy assumes query-major flat order; verify that
+        # (qidx, cols) really is the implied layout rather than trusting it
+        uniform = (d and int(counts.min()) == d == int(counts.max())
+                   and qidx.shape[0] == n_queries * d
+                   and bool((cols.reshape(grid) == np.arange(d)).all())
+                   and bool((qidx.reshape(grid)
+                             == np.arange(n_queries)[:, None]).all()))
+        dest = None if uniform else np.multiply(qidx, only.d_pad,
+                                                dtype=np.intp)
     else:
-        dest = np.multiply(qidx, d_pad, dtype=np.intp)
-        dest += col
-        tiebreak2.reshape(-1)[dest] = tiebreak
-        rel2.reshape(-1)[dest] = rel
-        judged2.reshape(-1)[dest] = judged
+        uniform = False
+        start = np.empty(n_queries, dtype=np.intp)  # each query's row start
+        topk = np.empty(n_queries, dtype=bool)
+        lo = 0
+        for c, size in zip(classes, sizes):
+            start[c.queries] = lo + np.arange(len(c.queries)) * c.d_pad
+            topk[c.queries] = c.topk
+            lo += size
+        cols = np.where(topk[qidx], tiebreak, col)
+        dest = start[qidx]
+    if uniform:
+        for flat, field in ((tiebreak_all, tiebreak), (rel_all, rel),
+                            (judged_all, judged)):
+            flat.reshape(only.q_pad, only.d_pad)[:n_queries, :d] = \
+                field.reshape(grid)
+    else:
+        dest += cols
+        tiebreak_all[dest] = tiebreak
+        rel_all[dest] = rel
+        judged_all[dest] = judged
 
-    ideal = np.zeros((q_pad, j_pad), dtype=np.float32)
-    w = min(j_pad, ideal_rows.shape[1])
-    ideal[:n_queries, :w] = ideal_rows[:, :w]
-    n_rel2 = np.zeros((q_pad,), dtype=np.float32)
-    n_rel2[:n_queries] = n_rel
-    n_nonrel2 = np.zeros((q_pad,), dtype=np.float32)
-    n_nonrel2[:n_queries] = n_judged_nonrel
-    qmask = np.zeros((q_pad,), dtype=bool)
-    qmask[:n_queries] = True
-    static = EvalBatch(
-        scores=None, tiebreak=tiebreak2, rel=rel2, judged=judged2,
-        mask=mask2, ideal_rel=ideal, n_rel=n_rel2,
-        n_judged_nonrel=n_nonrel2, query_mask=qmask,
-    )
-    for a in (*static[1:], dest):
+    static, lo = [], 0
+    for c, size in zip(classes, sizes):
+        n = len(c.queries)
+        shape = (c.q_pad, c.d_pad)
+        tiebreak2, rel2, judged2, mask2 = (
+            flat[lo:lo + size].reshape(shape)
+            for flat in (tiebreak_all, rel_all, judged_all, mask_all))
+        lo += size
+        mask2[:n] = (np.arange(c.d_pad, dtype=np.int64)[None, :]
+                     < counts[c.queries][:, None])
+        ideal = np.zeros((c.q_pad, c.j_pad), dtype=np.float32)
+        w = min(c.j_pad, ideal_rows.shape[1])
+        ideal[:n, :w] = ideal_rows[c.queries, :w]
+        n_rel2 = np.zeros((c.q_pad,), dtype=np.float32)
+        n_rel2[:n] = n_rel[c.queries]
+        n_nonrel2 = np.zeros((c.q_pad,), dtype=np.float32)
+        n_nonrel2[:n] = n_judged_nonrel[c.queries]
+        qmask = np.zeros((c.q_pad,), dtype=bool)
+        qmask[:n] = True
+        static.append(EvalBatch(
+            scores=None, tiebreak=tiebreak2, rel=rel2, judged=judged2,
+            mask=mask2, ideal_rel=ideal, n_rel=n_rel2,
+            n_judged_nonrel=n_nonrel2, query_mask=qmask,
+        ))
+    for a in (*(f for s in static for f in s[1:]), dest):
         if a is not None:
             a.flags.writeable = False
-    return FlatLayout(static, dest, n_queries, d)
+    return FlatLayout(classes, tuple(static), dest, n_queries, d)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Spans and marks of the program's own layers, on the profiler's clock.
+"""Spans, marks and counts of the program's own layers, on the profiler's
+clock.
 
 Recording is on exactly while a JAX profiler session records
 (``jax.profiler.TraceAnnotation.is_enabled()``): the profiler is the one
@@ -11,8 +12,10 @@ things:
 * it keeps one :class:`Record` in memory, read back in the same process
   with :func:`records` once the traced window has ended.
 
-While it is off, :func:`span` returns one shared no-op context, so a span
-on the hot path costs an ``is_enabled()`` check and an empty ``with``.
+:func:`mark` and :func:`count` keep zero-length records, a count's
+carrying a number.  While it is off, :func:`span` returns one shared
+no-op context, so a span on the hot path costs an ``is_enabled()`` check
+and an empty ``with``.
 
 Parents are tracked per thread: the service evaluates on
 ``asyncio.to_thread`` workers, and a span opened there takes the span open
@@ -34,20 +37,23 @@ from typing import List, NamedTuple, Optional
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Record", "span", "mark", "records", "dropped", "clear"]
+__all__ = ["Record", "span", "mark", "count", "records", "dropped",
+           "clear"]
 
 #: records kept at most; spans past it are counted by :func:`dropped`
 CAPACITY = 1 << 20
 
 
 class Record(NamedTuple):
-    """One span (or a zero-length mark) while the profiler recorded."""
+    """One span (or a zero-length mark or count) while the profiler
+    recorded."""
 
     name: str
     parent: Optional[int]  # index of the span open on this thread, or None
     start_ns: int  # time.perf_counter_ns()
     end_ns: Optional[int]  # None while the span is open
     thread_id: int
+    value: Optional[int] = None  # the number a count carries
 
 
 _is_enabled = TraceAnnotation.is_enabled
@@ -70,11 +76,13 @@ def _stack() -> List[Optional[int]]:
 
 
 def _keep(name: str, parent: Optional[int], start_ns: int,
-          end_ns: Optional[int]) -> Optional[int]:
+          end_ns: Optional[int], value: Optional[int] = None
+          ) -> Optional[int]:
     """Append one record; its index, or None once :data:`CAPACITY` records
     are kept."""
     global _dropped
-    rec = Record(name, parent, start_ns, end_ns, threading.get_ident())
+    rec = Record(name, parent, start_ns, end_ns, threading.get_ident(),
+                 value)
     with _lock:
         if len(_records) >= CAPACITY:
             _dropped += 1
@@ -122,10 +130,17 @@ def span(name: str):
 def mark(name: str) -> None:
     """A zero-length record under the span open on this thread, while the
     profiler records (``repro.compile.<engine>`` at each retrace)."""
+    count(name, None)
+
+
+def count(name: str, value: Optional[int]) -> None:
+    """A zero-length record carrying ``value`` under the span open on this
+    thread, while the profiler records (``repro.batch.rows`` and
+    ``repro.batch.cells`` for each batch sent to the device)."""
     if _is_enabled():
         stack = _stack()
         now = time.perf_counter_ns()
-        _keep(name, stack[-1] if stack else None, now, now)
+        _keep(name, stack[-1] if stack else None, now, now, value)
 
 
 def records() -> List[Record]:

@@ -52,6 +52,9 @@ def smoke(monkeypatch):
     # depth > 512 keeps the padded document axis wide enough for top-k
     monkeypatch.setattr(chip_smoke, "MSMARCO_DEV", chip_smoke.DevSetShape(
         queries=20, depth=600, sample=12))
+    # lists up to 600 documents: the widest class is 1,024, on top-k
+    monkeypatch.setattr(chip_smoke, "RAGGED", chip_smoke.RaggedShape(
+        queries=40, longest=600))
     monkeypatch.setattr(chip_smoke, "require_tpu",
                         lambda count: jax.devices()[:count])
     monkeypatch.setattr(chip_smoke, "expect_kernel", lambda text, where: None)
@@ -63,7 +66,7 @@ def smoke(monkeypatch):
 def test_chip_smoke_phases_rehearse_on_cpu(smoke, capsys):
     assert smoke.main(["--seed", "3"]) == 0
     lines = capsys.readouterr().out.strip().splitlines()
-    for phase in ("[A ", "[B ", "[C ", "[D "):
+    for phase in ("[A ", "[B ", "[C ", "[D ", "[G "):
         assert any(line.startswith(phase) for line in lines), phase
     assert "route=topk_kernel" in next(l for l in lines if l.startswith("[B"))
     last = json.loads(lines[-1])

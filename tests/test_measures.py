@@ -9,6 +9,7 @@ import pytest
 
 from repro.baselines import native_ndcg, pure_eval
 from repro.core import RelevanceEvaluator, aggregate_results
+from repro.kernels import bucketing
 
 MEASURES = ("map", "ndcg", "ndcg_cut", "P", "recall", "recip_rank", "Rprec",
             "bpref", "success", "map_cut", "num_ret", "num_rel",
@@ -188,7 +189,7 @@ def test_full_depth_measure_disables_topk_route(monkeypatch):
 
     # narrow batches stay on the full sort too (top-k gains nothing there)
     ev2 = RelevanceEvaluator({"q": {"d1": 1}}, ("P_10",))
-    assert not ev2._route_topk(ev2.tokenize_run({"q": {"d1": 1.0}}))
+    assert not ev2._route_topk(bucketing.bucket_docs(1))
 
 
 def test_topk_path_preserves_trec_tie_rule(monkeypatch):

@@ -76,6 +76,34 @@ def test_one_root_with_the_five_layers_in_order(ev, tmp_path, entry):
         assert a.end_ns <= b.start_ns
 
 
+@pytest.mark.parametrize("session", [False, True], ids=["off", "profiled"])
+def test_a_count_carries_its_value_only_under_a_session(ev, tmp_path,
+                                                        session):
+    with (profiled(tmp_path) if session else contextlib.nullcontext()):
+        obs.clear()
+        with obs.span("repro.a"):
+            obs.count("repro.n", 7)
+        obs.count("repro.m", 0)
+        ev.evaluate_buffer(ev.tokenize_run(RUN))
+    recs = obs.records()
+    if not session:
+        assert recs == []
+        return
+    named = {r.name: (i, r) for i, r in enumerate(recs)}
+    i_a, _ = named["repro.a"]
+    _, n = named["repro.n"]
+    assert (n.parent, n.value, n.start_ns) == (i_a, 7, n.end_ns)
+    assert named["repro.m"][1].parent is None
+    assert named["repro.m"][1].value == 0
+    assert named["repro.a"][1].value is None  # spans carry no number
+    # the evaluator counts the one batch of this two-query buffer
+    i_t, _ = named["repro.transfer"]
+    rows = [r for r in recs if r.name == "repro.batch.rows"]
+    cells = [r for r in recs if r.name == "repro.batch.cells"]
+    assert [(r.parent, r.value) for r in rows] == [(i_t, 5)]
+    assert [(r.parent, r.value) for r in cells] == [(i_t, 2 * 8)]
+
+
 def test_a_worker_thread_takes_its_own_parent(tmp_path):
     index = {}
 

@@ -92,3 +92,21 @@ def test_topk_measure_core_compiles_for_v5e(one_chip, monkeypatch):
     compiled = M.compute_measures_topk_jit.lower(
         _batch_spec(one_chip, 8192, 1024, 8), parsed, 1.0, False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("q,d,topk_route", [
+    (64, 8, False), (16384, 128, False), (4096, 512, False),
+    (512, 1024, True), (64, 2048, True)],
+    ids=lambda v: str(v))
+def test_depth_class_measure_cores_compile_for_v5e(one_chip, monkeypatch, q,
+                                                   d, topk_route):
+    """The depth classes of MSLR-WEB30K's ragged lists (every listed
+    document judged, so the ideal axis is as wide as the list axis): the
+    narrow ones on the full sort, the wide ones on the top-k kernel."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    parsed = M.parse_measures(("nDCG@5", "nDCG@10"))
+    core = (M.compute_measures_topk_jit if topk_route
+            else M.compute_measures_jit)
+    compiled = core.lower(_batch_spec(one_chip, q, d, d), parsed, 1.0,
+                          False).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == topk_route
