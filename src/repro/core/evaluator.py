@@ -917,7 +917,9 @@ class RelevanceEvaluator:
         measure core is launched before one wait.  The copy and the measure
         cores each end on the device (``block_until_ready``), with or
         without a profiler session, so a traced call runs the same schedule
-        as an untraced one.
+        as an untraced one.  Each class's core returns its columns packed
+        in one ``[K, q_pad]`` array, rows in ``measure_keys`` order; every
+        class's copy to the host is started before any is read.
         """
         with obs.span("repro.transfer"):
             for c in classes:
@@ -925,36 +927,28 @@ class RelevanceEvaluator:
                 obs.count("repro.batch.cells", c.q_pad * c.d_pad)
             batches = jax.block_until_ready(jax.device_put(list(batches)))
         with obs.span("repro.compute"):
-            per_class = jax.block_until_ready([
-                (M.compute_measures_topk_jit if c.topk
-                 else M.compute_measures_jit)(
+            packed = jax.block_until_ready([
+                (M.compute_measures_topk_packed_jit if c.topk
+                 else M.compute_measures_packed_jit)(
                     batch, self.measures, self.relevance_level,
                     self.judged_docs_only)
                 for c, batch in zip(classes, batches)])
         keys = self.measure_keys
         nq = sum(len(qids) for qids in groups)
         with obs.span("repro.fetch"):
-            if len(classes) == 1:
-                cols = {k: np.asarray(per_class[0][k])[:nq].tolist()
-                        for k in keys}
-            else:
-                cols = {k: _stitch(classes, [pq[k] for pq in per_class], nq)
-                        for k in keys}
+            for p in packed:
+                obs.count("repro.fetch.copy", p.nbytes)
+                p.copy_to_host_async()
+            rows = np.empty((len(keys), nq), dtype=np.float32)
+            for c, p in zip(classes, packed):
+                rows[:, c.queries] = np.asarray(p)[:, :len(c.queries)]
+            cols = dict(zip(keys, rows.tolist()))
         with obs.span("repro.results"):
             lo = 0
             for out, qids in zip(outs, groups):
                 for i, qid in enumerate(qids, lo):
                     out[qid] = {k: cols[k][i] for k in keys}
                 lo += len(qids)
-
-
-def _stitch(classes: Sequence[M.DepthClass], columns, nq: int) -> list:
-    """One measure's per-class columns back in the buffer's query order."""
-    parts = [np.asarray(column) for column in columns]
-    out = np.empty(nq, dtype=parts[0].dtype)
-    for c, part in zip(classes, parts):
-        out[c.queries] = part[:len(c.queries)]
-    return out.tolist()
 
 
 def aggregate_results(results: Dict[str, Dict[str, float]]) -> Dict[str, float]:

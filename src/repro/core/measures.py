@@ -473,6 +473,40 @@ def compute_measures_topk_jit(batch, measures, relevance_level=1.0,
                                  judged_only)
 
 
+def pack_columns(columns: Dict[str, jax.Array], measures,
+                 q: int) -> jax.Array:
+    """The ``[Q]`` columns as one ``[K, Q]`` array, rows in
+    ``registry.keys_for(measures)`` order (``measure_keys``'s)."""
+    keys = registry.keys_for(measures)
+    if not keys:
+        return jnp.zeros((0, q), jnp.float32)
+    return jnp.stack([columns[k] for k in keys])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def compute_measures_packed_jit(batch, measures, relevance_level=1.0,
+                                judged_only=False):
+    """:func:`compute_measures_jit`'s columns packed by
+    :func:`pack_columns` in the same program: one output to fetch."""
+    from repro.kernels import bucketing
+    bucketing.record_trace("measure_core")
+    return pack_columns(
+        compute_measures(batch, measures, relevance_level, judged_only),
+        measures, batch.query_mask.shape[0])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def compute_measures_topk_packed_jit(batch, measures, relevance_level=1.0,
+                                     judged_only=False):
+    """:func:`compute_measures_topk_jit`'s columns packed by
+    :func:`pack_columns` in the same program."""
+    from repro.kernels import bucketing
+    bucketing.record_trace("measure_core_topk")
+    return pack_columns(
+        compute_measures_topk(batch, measures, relevance_level, judged_only),
+        measures, batch.query_mask.shape[0])
+
+
 def aggregate(per_query: Dict[str, jax.Array], query_mask: jax.Array) -> Dict[str, jax.Array]:
     """Mean over real queries (trec_eval 'all' row)."""
     n = jnp.maximum(jnp.sum(query_mask.astype(jnp.float32)), 1.0)

@@ -136,7 +136,8 @@ def mark(name: str) -> None:
 def count(name: str, value: Optional[int]) -> None:
     """A zero-length record carrying ``value`` under the span open on this
     thread, while the profiler records (``repro.batch.rows`` and
-    ``repro.batch.cells`` for each batch sent to the device)."""
+    ``repro.batch.cells`` for each batch sent to the device,
+    ``repro.fetch.copy`` for each copy back to the host)."""
     if _is_enabled():
         stack = _stack()
         now = time.perf_counter_ns()

@@ -6,7 +6,9 @@ or top-k) and measured on its own, and the answers come back in the
 buffer's query order.  Every entry point must agree with the plain
 reference (``baselines/pure_eval.py``) within 1e-5, a uniform buffer must
 stay the one rectangle it always was, and the split must be built once per
-buffer and compile a closed set of signatures.
+buffer and compile a closed set of signatures.  Each class's columns come
+back packed in one ``[K, q_pad]`` array, fetched in one copy, with the
+bits the per-key dict cores give.
 """
 
 import contextlib
@@ -18,6 +20,8 @@ import pytest
 from repro import obs
 from repro.baselines import pure_eval
 from repro.core import RelevanceEvaluator
+from repro.core import measures as M
+from repro.core.evaluator import concat_run_buffers
 from repro.kernels import bucketing
 
 TOL = 1e-5
@@ -25,7 +29,13 @@ TOL = 1e-5
 FIXED_LENGTHS = [1, 3, 8, 9, 16, 17, 40, 100, 200, 300, 513, 600]
 UNBOUNDED = ("map", "ndcg", "recip_rank", "bpref", "Rprec")
 BOUNDED = ("nDCG@5", "nDCG@10", "P@10")
-REFERENCE = {UNBOUNDED: UNBOUNDED, BOUNDED: ("ndcg_cut", "P")}
+#: the Robust04 configuration's measures: 32 columns
+ROBUST04 = ("map", "bpref", "ndcg", "Rprec", "recip_rank", "P", "recall",
+            "ndcg_cut")
+REFERENCE = {UNBOUNDED: UNBOUNDED, BOUNDED: ("ndcg_cut", "P"),
+             ROBUST04: ROBUST04}
+MEASURE_SETS = {"unbounded": UNBOUNDED, "bounded": BOUNDED,
+                "robust04": ROBUST04}
 
 
 def ragged_case(seed, nq=24, max_len=600, no_rel=3):
@@ -46,6 +56,24 @@ def ragged_case(seed, nq=24, max_len=600, no_rel=3):
         scores = np.round(2 * (grades[:n] + rng.normal(size=n))) / 2
         run[qid] = dict(zip(docs[:n], scores.tolist()))
     return qrel, run
+
+
+def uniform_case(seed, nq=13, depth=600):
+    """``ragged_case``'s grades and scores with every list ``depth`` long:
+    one depth class, on the top-k route for depth-bounded measures."""
+    rng = np.random.default_rng(seed)
+    qrel, run = {}, {}
+    for q in range(nq):
+        qid = f"q{q:03d}"
+        docs = [f"{qid}-d{j:04d}" for j in rng.permutation(depth + 4)]
+        grades = rng.choice(5, depth + 4, p=[0.52, 0.32, 0.13, 0.02, 0.01])
+        qrel[qid] = dict(zip(docs, grades.tolist()))
+        scores = np.round(2 * (grades[:depth] + rng.normal(size=depth))) / 2
+        run[qid] = dict(zip(docs[:depth], scores.tolist()))
+    return qrel, run
+
+
+CASES = {"one-class": uniform_case, "ragged": ragged_case}
 
 
 def rescore(run, seed):
@@ -83,8 +111,8 @@ def profiled(log_dir):
 
 
 @pytest.mark.parametrize("seed", [0, 1])
-@pytest.mark.parametrize("measures", [UNBOUNDED, BOUNDED],
-                         ids=["unbounded", "bounded"])
+@pytest.mark.parametrize("measures", [UNBOUNDED, BOUNDED, ROBUST04],
+                         ids=["unbounded", "bounded", "robust04"])
 @pytest.mark.parametrize("entry", ["evaluate_buffer", "evaluate_buffers",
                                    "evaluate"])
 def test_entry_points_agree_with_the_reference(entry, measures, seed):
@@ -203,3 +231,112 @@ def test_compiled_signatures_stay_in_the_closed_set():
                                         minimum=bucketing.MIN_DOC_BUCKET))
     assert 0 < compiled <= bound
     assert compiled < batches  # classes of one padding share one program
+
+
+def by_dict_cores(ev, bufs):
+    """Each buffer's results as the evaluator gave them before its columns
+    were packed: the buffers end to end, each class's dict core, one fetch
+    per key, put back in query order and split by buffer."""
+    both = concat_run_buffers(bufs)
+    classes, batches = ev._class_batches(both)
+    cols = {k: np.empty(len(both), np.float32) for k in ev.measure_keys}
+    for c, batch in zip(classes, batches):
+        core = M.compute_measures_topk_jit if c.topk else M.compute_measures_jit
+        got = core(batch, ev.measures, ev.relevance_level,
+                   ev.judged_docs_only)
+        for k, col in cols.items():
+            col[c.queries] = np.asarray(got[k])[:len(c.queries)]
+    out, lo = [], 0
+    for buf in bufs:
+        out.append({q: {k: cols[k][i] for k in ev.measure_keys}
+                    for i, q in enumerate(buf.qids, lo)})
+        lo += len(buf)
+    return out
+
+
+def assert_same_bits(got, want, keys):
+    assert list(got) == list(want)
+    for qid in want:
+        assert list(got[qid]) == list(keys), qid
+    bits = lambda res: np.array(  # noqa: E731
+        [[res[q][k] for k in keys] for q in want], np.float32).view(np.int32)
+    np.testing.assert_array_equal(bits(got), bits(want))
+
+
+@pytest.mark.parametrize("shape", CASES)
+@pytest.mark.parametrize("measures", MEASURE_SETS)
+@pytest.mark.parametrize("entry", ["evaluate_buffer", "evaluate_buffers",
+                                   "evaluate"])
+def test_packed_columns_are_the_dict_cores_bits(entry, measures, shape):
+    qrel, run = CASES[shape](4)
+    ev = RelevanceEvaluator(qrel, MEASURE_SETS[measures])
+    buf = ev.tokenize_run(run)
+    classes = classes_of(ev, buf)
+    assert (len(classes) == 1) == (shape == "one-class")
+    assert any(c.topk for c in classes) == (measures == "bounded")
+    other = rescore(run, 5)
+    other_buf = ev.tokenize_run(other)
+    want = [by_dict_cores(ev, [b])[0] for b in (buf, other_buf)]
+    if entry == "evaluate_buffer":
+        got = [ev.evaluate_buffer(buf),
+               ev.evaluate_buffer(buf, scores=flat_scores(other))]
+    elif entry == "evaluate_buffers":
+        # an empty group between the two keeps its place and gets nothing
+        got = ev.evaluate_buffers([buf, ev.tokenize_run({}), buf],
+                                  [None, None, flat_scores(other)])
+        assert got.pop(1) == {}
+        # the buffers share one padded axis, so they share its programs
+        want = by_dict_cores(ev, [buf, other_buf])
+    else:
+        got = [ev.evaluate(run), ev.evaluate(other)]
+    for g, w in zip(got, want):
+        assert_same_bits(g, w, ev.measure_keys)
+
+
+@pytest.mark.parametrize("route,measures", [
+    ("full-sort", ROBUST04), ("top-k", BOUNDED), ("full-sort", ())],
+    ids=["full-sort", "top-k", "no-measures"])
+def test_packed_cores_stack_the_dict_columns_in_key_order(route, measures):
+    qrel, run = uniform_case(6)
+    ev = RelevanceEvaluator(qrel, measures)
+    buf = ev.tokenize_run(run)
+    (only,) = classes_of(ev, buf)
+    assert only.topk == (route == "top-k")
+    batch = ev.batch_from_buffer(buf, topk_layout=only.topk)
+    dict_core, packed_core = {
+        "full-sort": (M.compute_measures_jit, M.compute_measures_packed_jit),
+        "top-k": (M.compute_measures_topk_jit,
+                  M.compute_measures_topk_packed_jit)}[route]
+    args = (batch, ev.measures, ev.relevance_level, ev.judged_docs_only)
+    columns, packed = dict_core(*args), np.asarray(packed_core(*args))
+    assert packed.shape == (len(ev.measure_keys), only.q_pad)
+    assert packed.dtype == np.float32
+    want = np.array([np.asarray(columns[k]) for k in ev.measure_keys],
+                    np.float32).reshape(packed.shape)
+    np.testing.assert_array_equal(packed.view(np.int32), want.view(np.int32))
+    if not measures:
+        assert ev.evaluate(run) == {q: {} for q in run}
+
+
+@pytest.mark.parametrize("shape", CASES)
+@pytest.mark.parametrize("entry", ["evaluate_buffer", "evaluate_buffers",
+                                   "evaluate"])
+def test_one_fetch_copy_per_class_per_call(entry, shape, tmp_path):
+    qrel, run = CASES[shape](7)
+    ev = RelevanceEvaluator(qrel, BOUNDED)
+    buf = ev.tokenize_run(run)
+    classes = classes_of(ev, buf)
+    call = {"evaluate_buffer": lambda: ev.evaluate_buffer(buf),
+            "evaluate_buffers": lambda: ev.evaluate_buffers(
+                [buf, ev.tokenize_run({})]),
+            "evaluate": lambda: ev.evaluate(run)}[entry]
+    call()  # compiles outside the session
+    with profiled(tmp_path):
+        for _ in range(3):
+            call()
+    recs = obs.records()
+    copies = [r for r in recs if r.name == "repro.fetch.copy"]
+    assert [r.value for r in copies] == [
+        4 * len(ev.measure_keys) * c.q_pad for c in classes] * 3
+    assert {recs[r.parent].name for r in copies} == {"repro.fetch"}
+    assert [r.name for r in recs].count("repro.evaluate") == 3

@@ -156,13 +156,13 @@ def test_topk_route_taken_and_bit_identical(monkeypatch, judged_only):
     run, qrel = _wide_case()
     ev = RelevanceEvaluator(qrel, BOUNDED, judged_docs_only=judged_only)
     calls = []
-    real = M.compute_measures_topk_jit
+    real = M.compute_measures_topk_packed_jit
 
     def spy(*args, **kwargs):
         calls.append(1)
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(M, "compute_measures_topk_jit", spy)
+    monkeypatch.setattr(M, "compute_measures_topk_packed_jit", spy)
     routed = ev.evaluate(run)
     assert calls, "wide depth-bounded batch must take the top-k path"
 
@@ -183,7 +183,7 @@ def test_full_depth_measure_disables_topk_route(monkeypatch):
     run, qrel = _wide_case(nq=1)
     ev = RelevanceEvaluator(qrel, ("map", "P_10"))  # map needs the full sort
     monkeypatch.setattr(
-        M, "compute_measures_topk_jit",
+        M, "compute_measures_topk_packed_jit",
         lambda *a, **k: pytest.fail("top-k path taken for full-depth map"))
     ev.evaluate(run)
 

@@ -110,3 +110,28 @@ def test_depth_class_measure_cores_compile_for_v5e(one_chip, monkeypatch, q,
     compiled = core.lower(_batch_spec(one_chip, q, d, d), parsed, 1.0,
                           False).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == topk_route
+
+
+ROBUST04 = ("map", "bpref", "ndcg", "Rprec", "recip_rank", "P", "recall",
+            "ndcg_cut")
+
+
+@pytest.mark.parametrize("q,d,j,measures,topk_route", [
+    (256, 1024, 2048, ROBUST04, False),
+    (16384, 128, 128, ("nDCG@5", "nDCG@10"), False),
+    (512, 1024, 1024, ("nDCG@5", "nDCG@10"), True)],
+    ids=["robust04", "mslr-128", "mslr-1024"])
+def test_packed_measure_cores_compile_for_v5e(one_chip, monkeypatch, q, d, j,
+                                              measures, topk_route):
+    """The packed cores the evaluator launches, at Robust04's shape and two
+    of MSLR-WEB30K's depth classes: one ``[K, q]`` float32 output."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    parsed = M.parse_measures(measures)
+    core = (M.compute_measures_topk_packed_jit if topk_route
+            else M.compute_measures_packed_jit)
+    lowered = core.lower(_batch_spec(one_chip, q, d, j), parsed, 1.0, False)
+    assert lowered.out_info.shape == (len(M.measure_keys(measures)), q)
+    assert lowered.out_info.dtype == jnp.float32
+    compiled = lowered.compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == topk_route
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
