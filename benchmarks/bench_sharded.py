@@ -4,8 +4,9 @@ One process, one mesh per device count: the first 1, 2, 4 and 8 of
 ``jax.devices()`` (counts above the visible devices are skipped).  Each
 mesh evaluates the same synthesized collection (paper §3 protocol),
 tokenized once, and times the steady-state sharded step —
-``ShardedEvaluator.evaluate_buffer`` on the cached ``RunBuffer``: numeric
-scatter → shard_map → fused kernel per shard → one psum.
+``ShardedEvaluator.evaluate_buffer`` on the cached ``RunBuffer``: the
+evaluator's depth classes, each class's packed measure core under
+``shard_map`` over the mesh, one host copy per class.
 ``speedup_vs_1dev`` is the wall-clock ratio against the 1-device mesh.
 
 On a TPU host the devices are the chips.  On a CPU host there is one device

@@ -9,10 +9,9 @@ Segments (repeat ``--only`` to pick several):
 * ``densify``   — run→``EvalBatch`` conversion in isolation: seed per-query
   loop vs the vectorized flat pipeline (cold dict ingest) vs the
   pre-tokenized session path (``batch_from_buffer`` on a ``RunBuffer``).
-* ``kernels``   — kernel-layer roofline: fused-measures achieved vs peak
-  bytes/s, execution mode (``ops.interpret_mode()``), autotuned
-  ``block_q``, and the compile-count accounting behind shape bucketing; see
-  ``bench_kernels``.
+* ``kernels``   — the compile-count accounting behind shape bucketing:
+  distinct wave sizes through the packed measure core vs the closed set of
+  signatures; see ``bench_kernels``.
 * ``sharded``   — multi-device scaling of the sharded evaluation pipeline
   (``repro.distributed.sharded_evaluator``) over meshes of the first
   1/2/4/8 visible devices, in this process; see ``bench_sharded``.
@@ -148,14 +147,9 @@ def main(argv=None) -> None:
               f"{row['session_us']:.1f},"
               f"speedup={row['speedup_densify']:.2f}")
     for row in results.get("kernels", []):
-        if row["segment"] == "fused_roofline":
-            print(f"kernels_fused_q{row['n_queries']}_d{row['n_docs']},"
-                  f"{row['us_per_call']:.1f},"
-                  f"block_q={row['block_q']}")
-        else:
-            print(f"kernels_bucketing_w{row['distinct_wave_sizes']},"
-                  f"{1e6 * row['elapsed_s'] / row['distinct_wave_sizes']:.1f},"
-                  f"compiles={row['compiles']}/{row['signature_bound']}")
+        print(f"kernels_bucketing_w{row['distinct_wave_sizes']},"
+              f"{1e6 * row['elapsed_s'] / row['distinct_wave_sizes']:.1f},"
+              f"compiles={row['compiles']}/{row['signature_bound']}")
     for row in results.get("sharded", []):
         sp = row.get("speedup_vs_1dev")
         sp_str = f"{sp:.2f}" if sp is not None else "nan"

@@ -14,15 +14,18 @@ every phase checks its answers against the plain reference engine
 * **B · sparse dev set** (MS MARCO passage dev shape: 6,980 queries x depth
   1,000, ~1.07 binary judgments per query) ingested with
   ``buffer_from_arrays``; depth-bounded measures take the top-k kernel.
-* **C · fused kernel**: A's collection through ``ShardedEvaluator`` on the
-  one-chip mesh (the CLI's ``--sharded`` path).
+* **C · sharded**: G's ragged lists through ``ShardedEvaluator`` on the
+  chip's mesh (the CLI's ``--sharded`` path): the evaluator's depth
+  classes, the wide ones on the top-k kernel under ``shard_map``, with the
+  bits ``evaluate_buffer`` gives.
 * **D · served**: an in-process ``serve_tcp`` service answering concurrent
   ``repro.client`` requests on A's collection; they must coalesce and equal
   the direct evaluation.
 * **E · cluster** (``--chips 4``): the router over 4 worker processes, one
   chip each; this process stays off JAX while they run.
 * **F · sharded mesh** (``--chips 4``): ``ShardedEvaluator`` over a 4-chip
-  mesh against single-device evaluation of the same data.
+  mesh against single-device evaluation of the same data: A's collection
+  on the full sort and G's ragged lists on both routes.
 * **G · ragged graded lists** (learning-to-rank shape: a few hundred
   queries, 1 to 1,251 documents each, every one judged 0-4) through
   ``evaluate_buffer``, twice: the buffer is evaluated in depth classes,
@@ -348,7 +351,7 @@ def phase_b(seed: int) -> None:
         raise SmokeFailure(f"B: expected the top-k route, got {topk} top-k "
                            f"and {full} full-sort compile(s)")
     batch = ev.batch_from_buffer(buf, topk_layout=True)
-    expect_kernel(M.compute_measures_topk_jit.lower(
+    expect_kernel(M.compute_measures_topk_packed_jit.lower(
         batch, ev.measures, ev.relevance_level,
         ev.judged_docs_only).compile().as_text(), "B (top-k measure core)")
 
@@ -368,27 +371,52 @@ def phase_b(seed: int) -> None:
            checked_queries=len(want), max_abs_diff=f"{diff:.3g}", **fields)
 
 
-def phase_c(coll) -> None:
-    from repro.core import RelevanceEvaluator
+def sharded_run(coll, measures, reference, mesh=None):
+    """``coll`` through ``ShardedEvaluator`` on ``mesh`` (default: every
+    device) beside ``evaluate_buffer``; the larger difference of the two
+    from the reference, the depth classes and each class's compiled
+    sharded program."""
+    from repro.core import RelevanceEvaluator, measures as M
     from repro.distributed import ShardedEvaluator
 
     qrel, qids, docnos, scores = coll
     want = pure_eval.evaluate(run_dict(qids, docnos, scores), qrel,
-                              MEASURES_A)
+                              reference)
     fields = {}
     with timed(fields):
-        ev = RelevanceEvaluator(qrel, MEASURES_A)
+        ev = RelevanceEvaluator(qrel, measures)
         buf = ev.buffer_from_arrays(qids, docnos, scores)
-        sev = ShardedEvaluator(ev)
+        sev = ShardedEvaluator(ev, mesh=mesh)
         got = sev.evaluate_buffer(buf).per_query
-    batch = ev.batch_from_buffer(buf, q_multiple=sev.n_shards)
-    expect_kernel(sev._dispatch.lower(batch).compile().as_text(),
-                  "C (sharded dispatch)")
-    diff = max_diff(got, want)
-    check("C (fused kernel)", diff)
-    report("C fused kernel", route="sharded_fused", mesh=sev.n_shards,
-           shape=f"{len(got)}x{ROBUST04.depth}",
-           max_abs_diff=f"{diff:.3g}", **fields)
+    single = ev.evaluate_buffer(buf)
+    classes, batches = ev._class_batches(buf, q_multiple=sev.n_shards)
+    texts = [M.packed_core(c.topk, sev.mesh).lower(
+        b, ev.measures, ev.relevance_level,
+        ev.judged_docs_only).compile().as_text()
+        for c, b in zip(classes, batches)]
+    diff = max(max_diff(got, want, ev.measure_keys),
+               max_diff(single, want, ev.measure_keys))
+    return {"ev": ev, "sev": sev, "got": got, "single": single,
+            "diff": diff, "fields": fields,
+            "classes": classes, "batches": batches, "texts": texts}
+
+
+def phase_c(seed: int) -> None:
+    r = sharded_run(ragged_collection(seed + 4), MEASURES_G, REFERENCE_G)
+    topk = [t for c, t in zip(r["classes"], r["texts"]) if c.topk]
+    if not topk or len(topk) == len(r["classes"]):
+        raise SmokeFailure("C: expected depth classes on both routes, got "
+                           f"{[(c.d_pad, c.topk) for c in r['classes']]}")
+    for text in topk:
+        expect_kernel(text, "C (sharded top-k core)")
+    if r["got"] != r["single"]:
+        raise SmokeFailure("C: sharded values differ from evaluate_buffer "
+                           f"by {max_diff(r['got'], r['single']):.3g}")
+    check("C (sharded)", r["diff"])
+    report("C sharded", route="sharded", mesh=r["sev"].n_shards,
+           classes=len(r["classes"]), topk_classes=len(topk),
+           bits_equal_evaluate_buffer=True,
+           max_abs_diff=f"{r['diff']:.3g}", **r["fields"])
 
 
 def phase_d(coll, seed: int) -> None:
@@ -525,38 +553,39 @@ def phase_e(coll, chip_ids) -> None:
            forwarded=forwarded, max_abs_diff=f"{worst:.3g}", **fields)
 
 
-def phase_f(coll, devices) -> None:
+def phase_f(coll, devices, seed: int) -> None:
     """ShardedEvaluator on a 4-chip mesh vs single-device evaluate."""
-    from jax.sharding import Mesh
+    import jax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    from repro.core import RelevanceEvaluator
-    from repro.distributed import ShardedEvaluator
+    from repro.core import measures as M
 
-    qrel, qids, docnos, scores = coll
-    want = pure_eval.evaluate(run_dict(qids, docnos, scores), qrel,
-                              MEASURES_A)
+    mesh = Mesh(devices[:4], ("data",))
+    runs = {"full-sort": sharded_run(coll, MEASURES_A, MEASURES_A, mesh),
+            "ragged": sharded_run(ragged_collection(seed + 4), MEASURES_G,
+                                  REFERENCE_G, mesh)}
     fields = {}
-    with timed(fields):
-        ev = RelevanceEvaluator(qrel, MEASURES_A)
-        buf = ev.buffer_from_arrays(qids, docnos, scores)
-        single = ev.evaluate_buffer(buf)
-        sev = ShardedEvaluator(ev, mesh=Mesh(devices[:4], ("data",)))
-        sharded = sev.evaluate_buffer(buf).per_query
-    batch = ev.batch_from_buffer(buf, q_multiple=sev.n_shards)
-    expect_kernel(sev._dispatch.lower(batch).compile().as_text(),
-                  "F (sharded dispatch)")
-    stacked, _ = sev._dispatch(batch)
-    placed = sorted({shard.device.id for shard in stacked.addressable_shards})
+    for name, r in runs.items():
+        vs_single = max_diff(r["got"], r["single"])
+        check(f"F ({name}, sharded vs single-device)", vs_single)
+        check(f"F ({name}, vs reference)", r["diff"])
+        fields[f"{name}_vs_single_max_abs_diff"] = f"{vs_single:.3g}"
+        fields[f"{name}_max_abs_diff"] = f"{r['diff']:.3g}"
+        fields[f"{name}_setup_s_cold"] = r["fields"]["setup_s_cold"]
+    ragged = runs["ragged"]
+    for c, text in zip(ragged["classes"], ragged["texts"]):
+        if c.topk:
+            expect_kernel(text, "F (sharded top-k core)")
+    c, batch, ev = ragged["classes"][-1], ragged["batches"][-1], ragged["ev"]
+    rows = M.packed_core(c.topk, mesh)(
+        jax.device_put(batch, NamedSharding(mesh, PartitionSpec("data"))),
+        ev.measures, ev.relevance_level, ev.judged_docs_only)
+    placed = sorted({shard.device.id for shard in rows.addressable_shards})
     if len(placed) != 4:
         raise SmokeFailure(f"F: shards landed on devices {placed}")
-    vs_single = max_diff(sharded, single)
-    check("F (sharded vs single-device)", vs_single)
-    diff = max(max_diff(sharded, want), max_diff(single, want))
-    check("F (vs reference)", diff)
-    report("F sharded mesh", mesh=sev.n_shards, shard_devices=placed,
-           shard_rows=stacked.addressable_shards[0].data.shape[0],
-           vs_single_max_abs_diff=f"{vs_single:.3g}",
-           max_abs_diff=f"{diff:.3g}", **fields)
+    report("F sharded mesh", mesh=ragged["sev"].n_shards,
+           shard_devices=placed,
+           shard_rows=rows.addressable_shards[0].data.shape[1], **fields)
 
 
 # -- main ---------------------------------------------------------------------
@@ -586,7 +615,7 @@ def one_chip(seed: int):
             phase_a(tmp, coll)
 
     run_phases([("A", a), ("B", lambda: phase_b(seed)),
-                ("C", lambda: phase_c(coll)),
+                ("C", lambda: phase_c(seed)),
                 ("D", lambda: phase_d(coll, seed)),
                 ("G", lambda: phase_g(seed))])
     return devices
@@ -602,7 +631,7 @@ def four_chips(seed: int):
 
     def f():
         devices.extend(require_tpu(4))
-        phase_f(coll, devices)
+        phase_f(coll, devices, seed)
 
     run_phases([("E", lambda: phase_e(coll, chips[:4])), ("F", f)])
     return devices

@@ -36,11 +36,8 @@ def kernel_roofline(bytes_moved: float, seconds: float,
     The kernel-benchmark counterpart of :func:`analyze`: instead of HLO
     cost estimates it takes *measured* wall time plus the analytic bytes
     the kernel must move (its HBM traffic floor) and reports achieved
-    bandwidth against :data:`HBM_BW` — the axis the fused measure kernel
-    lives on (it is memory-bound by construction: one [Q, D] read, a
-    [Q, 64] write, O(D log D) VPU work in between).  Consumed by
-    ``benchmarks.bench_kernels`` and the ``--only kernels`` segment, and
-    by ``kernels.autotune`` for its VMEM occupancy model.
+    bandwidth against :data:`HBM_BW`.  Consumed by
+    ``benchmarks.bench_kernels`` and the ``--only kernels`` segment.
     """
     achieved_bw = bytes_moved / seconds if seconds > 0 else 0.0
     achieved_flops = flops / seconds if seconds > 0 else 0.0

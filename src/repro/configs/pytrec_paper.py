@@ -28,11 +28,6 @@ _PARSED = M.parse_measures(MEASURES)
 class EvalConfig:
     name: str
     relevance_level: float = 1.0
-    # "sorted": batched sort engine (packed payload — §Perf iteration C2)
-    # "ranked": rank-reduction engine (core/ranked.py) — exact, collective-
-    #   minimal, but XLA:CPU materializes its compare-reduce; it is the
-    #   natural Pallas-kernel formulation (§Perf iteration C1 discussion)
-    engine: str = "sorted"
 
 
 SHAPES = {
@@ -50,8 +45,6 @@ def make_config(smoke: bool = False) -> EvalConfig:
 
 
 def _make_step(cfg: EvalConfig, shape: ShapeSpec, mesh) -> StepBundle:
-    from repro.core import ranked as RK
-
     q = shape.get("n_queries")
     d = shape.get("n_docs")
     j = shape.get("n_judged")
@@ -63,31 +56,16 @@ def _make_step(cfg: EvalConfig, shape: ShapeSpec, mesh) -> StepBundle:
     f32, i32, b_ = jnp.float32, jnp.int32, jnp.bool_
     sds = jax.ShapeDtypeStruct
 
-    if cfg.engine == "ranked":
-        def eval_step(batch: RK.RankedBatch):
-            per_q = RK.compute_measures_ranked(batch, _PARSED,
-                                               cfg.relevance_level)
-            return M.aggregate(per_q, batch.query_mask)
+    def eval_step(batch: M.EvalBatch):
+        per_q = M.compute_measures(batch, _PARSED, cfg.relevance_level)
+        return M.aggregate(per_q, batch.query_mask)
 
-        batch_abs = RK.RankedBatch(
-            scores=sds((q, d), f32), tiebreak=sds((q, d), i32),
-            mask=sds((q, d), b_),
-            judged_scores=sds((q, j), f32), judged_tiebreak=sds((q, j), i32),
-            judged_rel=sds((q, j), f32), judged_retrieved=sds((q, j), b_),
-            judged_mask=sds((q, j), b_), ideal_rel=sds((q, j), f32),
-            n_rel=sds((q,), f32), n_judged_nonrel=sds((q,), f32),
-            query_mask=sds((q,), b_))
-    else:
-        def eval_step(batch: M.EvalBatch):
-            per_q = M.compute_measures(batch, _PARSED, cfg.relevance_level)
-            return M.aggregate(per_q, batch.query_mask)
-
-        batch_abs = M.EvalBatch(
-            scores=sds((q, d), f32), tiebreak=sds((q, d), i32),
-            rel=sds((q, d), f32), judged=sds((q, d), b_),
-            mask=sds((q, d), b_),
-            ideal_rel=sds((q, j), f32), n_rel=sds((q,), f32),
-            n_judged_nonrel=sds((q,), f32), query_mask=sds((q,), b_))
+    batch_abs = M.EvalBatch(
+        scores=sds((q, d), f32), tiebreak=sds((q, d), i32),
+        rel=sds((q, d), f32), judged=sds((q, d), b_),
+        mask=sds((q, d), b_),
+        ideal_rel=sds((q, j), f32), n_rel=sds((q,), f32),
+        n_judged_nonrel=sds((q,), f32), query_mask=sds((q,), b_))
     if mesh is not None:
         qaxes = tuple(mesh.axis_names)  # queries shard over EVERY axis
         in_specs = jax.tree.map(

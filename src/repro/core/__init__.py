@@ -21,9 +21,7 @@ from repro.core.measures import (
     FlatLayout,
     batch_from_dense,
     compute_measures,
-    compute_measures_jit,
     compute_measures_topk,
-    compute_measures_topk_jit,
     finalize_aggregates,
     flat_layout,
     measure_keys,
@@ -50,9 +48,7 @@ __all__ = [
     "EvalBatch",
     "batch_from_dense",
     "compute_measures",
-    "compute_measures_jit",
     "compute_measures_topk",
-    "compute_measures_topk_jit",
     "finalize_aggregates",
     "measure_keys",
     "parse_measures",

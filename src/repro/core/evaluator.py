@@ -61,6 +61,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 from repro.core import measures as M
@@ -489,14 +490,14 @@ class RelevanceEvaluator:
                           topk_layout: bool = False) -> M.EvalBatch:
         """Padded ``EvalBatch`` from a buffer (numeric work only).
 
-        Feed the result to ``core.measures.compute_measures_jit`` or to
+        Feed the result to ``core.measures.compute_measures_packed_jit``
+        (``compute_measures_topk_packed_jit`` under ``topk_layout``) or to
         ``core.streaming.metric_update`` inside a training loop.
 
         ``q_multiple`` is the shard-aware padding knob: the query axis is
         padded to a multiple of it (on top of the usual power-of-two
         bucketing), so the batch divides evenly over the query axis of a
-        device mesh.  ``repro.distributed.sharded_evaluator`` passes the mesh
-        size here; padded queries carry ``query_mask == False`` and are
+        device mesh.  Padded queries carry ``query_mask == False`` and are
         ignored by every measure and aggregate.
 
         ``topk_layout`` scatters each document at column == its tiebreak
@@ -508,8 +509,8 @@ class RelevanceEvaluator:
         along as its own field).
 
         The batch is one rectangle, padded to the buffer's longest list:
-        the evaluator's own calls split a buffer into depth classes
-        instead (:meth:`_class_batches`).
+        the evaluator's own calls, and ``ShardedEvaluator``'s, split a
+        buffer into depth classes instead (:meth:`_class_batches`).
 
         Only the scores slab is new on each call.  The rest of the batch
         and the scores' destinations (:class:`core.measures.FlatLayout`)
@@ -528,21 +529,23 @@ class RelevanceEvaluator:
             self._depth_class(buf, np.arange(nq), q_pad, bool(topk_layout))])
         return layout.batches(buf.scores)[0]
 
-    def _class_batches(self, buf: RunBuffer):
+    def _class_batches(self, buf: RunBuffer, q_multiple: int = 1):
         """``(classes, batches)``: one padded batch per depth class of
         ``buf``, each routed on its own padded depth.
 
         A query's class is the padding class of its own list length
         (``bucketing.bucket_docs``), so a ragged buffer is not padded to
         its longest list; a buffer whose lists share one class is one
-        batch, the one :meth:`batch_from_buffer` gives.  The split and each
-        class's static slabs are built once and kept on the buffer, like
-        :meth:`batch_from_buffer`'s layout.
+        batch, the one :meth:`batch_from_buffer` gives.  Each class's query
+        axis is padded to a multiple of ``q_multiple`` as well (the shard
+        count of a device mesh, as in :meth:`batch_from_buffer`).  The
+        split and each class's static slabs are built once and kept on the
+        buffer, like :meth:`batch_from_buffer`'s layout.
         """
         if buf.scores is None:
             raise ValueError("buffer has no scores; pass scores=")
-        layout = self._layout(buf, "depth classes",
-                              lambda: self._depth_classes(buf))
+        layout = self._layout(buf, ("depth classes", q_multiple),
+                              lambda: self._depth_classes(buf, q_multiple))
         return layout.classes, layout.batches(buf.scores)
 
     def _layout(self, buf: RunBuffer, key, classes) -> M.FlatLayout:
@@ -563,7 +566,8 @@ class RelevanceEvaluator:
         buf.layout[0] = (key, layout)
         return layout
 
-    def _depth_classes(self, buf: RunBuffer) -> List[M.DepthClass]:
+    def _depth_classes(self, buf: RunBuffer,
+                       q_multiple: int = 1) -> List[M.DepthClass]:
         """``buf``'s queries grouped by the padding class of their list
         length, shallowest first; each class padded and routed alone."""
         depths, of_query = np.unique(buf.counts, return_inverse=True)
@@ -573,7 +577,8 @@ class RelevanceEvaluator:
         for d_pad in np.unique(pads).tolist():
             queries = np.flatnonzero(pads == d_pad)
             classes.append(self._depth_class(
-                buf, queries, bucketing.bucket_queries(len(queries)),
+                buf, queries,
+                bucketing.bucket_queries(len(queries), multiple=q_multiple),
                 self._route_topk(d_pad)))
         return classes
 
@@ -683,9 +688,10 @@ class RelevanceEvaluator:
 
         Builds a :class:`repro.distributed.sharded_evaluator.ShardedEvaluator`
         over ``mesh`` (default: one 1-D mesh spanning ``jax.devices()``) and
-        evaluates ``run_or_buffer`` (a run mapping or a :class:`RunBuffer`).
-        Returns a ``ShardedResult`` with per-query results bit-identical to
-        :meth:`evaluate` plus corpus-mean aggregates.
+        evaluates ``run_or_buffer`` (a run mapping or a :class:`RunBuffer`)
+        in this evaluator's depth classes and measure cores, each class's
+        query axis split over the mesh.  Returns a ``ShardedResult``:
+        per-query results plus corpus-mean aggregates.
         """
         from repro.distributed.sharded_evaluator import ShardedEvaluator
 
@@ -905,45 +911,63 @@ class RelevanceEvaluator:
 
     # -- output ---------------------------------------------------------------
 
-    def _emit(self, outs: Sequence[Dict[str, Dict[str, float]]],
-              groups: Sequence[Sequence[str]],
-              classes: Sequence[M.DepthClass],
-              batches: Sequence[M.EvalBatch]) -> None:
-        """Measure each class's batch and fill ``outs[i][qid]`` for each
-        qid of ``groups[i]``; the groups lie end to end on the query axis
-        that the classes partition, and come out in that order.
+    def _measure_rows(self, classes: Sequence[M.DepthClass],
+                      batches: Sequence[M.EvalBatch],
+                      mesh=None) -> np.ndarray:
+        """The host ``[K, nq]`` float32 measure rows, in ``measure_keys``
+        order, of the ``nq`` queries that ``classes`` partition.
 
         Every batch goes to the device in one copy, and every class's
         measure core is launched before one wait.  The copy and the measure
         cores each end on the device (``block_until_ready``), with or
         without a profiler session, so a traced call runs the same schedule
         as an untraced one.  Each class's core returns its columns packed
-        in one ``[K, q_pad]`` array, rows in ``measure_keys`` order; every
-        class's copy to the host is started before any is read.
+        in one ``[K, q_pad]`` array; every class's copy to the host is
+        started before any is read.
+
+        ``mesh`` is a 1-D device mesh to split every batch's query axis
+        over, given only by
+        :class:`repro.distributed.sharded_evaluator.ShardedEvaluator`,
+        whose classes are padded to a multiple of its size: each device
+        then runs the same core on its share of the rows (``shard_map``).
+        ``None`` keeps every batch on the default device.
         """
+        placement = None if mesh is None else NamedSharding(
+            mesh, PartitionSpec(mesh.axis_names[0]))
         with obs.span("repro.transfer"):
             for c in classes:
                 obs.count("repro.batch.rows", c.docs)
                 obs.count("repro.batch.cells", c.q_pad * c.d_pad)
-            batches = jax.block_until_ready(jax.device_put(list(batches)))
+            batches = jax.block_until_ready(
+                jax.device_put(list(batches), placement))
         with obs.span("repro.compute"):
             packed = jax.block_until_ready([
-                (M.compute_measures_topk_packed_jit if c.topk
-                 else M.compute_measures_packed_jit)(
+                M.packed_core(c.topk, mesh)(
                     batch, self.measures, self.relevance_level,
                     self.judged_docs_only)
                 for c, batch in zip(classes, batches)])
-        keys = self.measure_keys
-        nq = sum(len(qids) for qids in groups)
+        nq = sum(len(c.queries) for c in classes)
         with obs.span("repro.fetch"):
             for p in packed:
                 obs.count("repro.fetch.copy", p.nbytes)
                 p.copy_to_host_async()
-            rows = np.empty((len(keys), nq), dtype=np.float32)
+            rows = np.empty((len(self.measure_keys), nq), dtype=np.float32)
             for c, p in zip(classes, packed):
                 rows[:, c.queries] = np.asarray(p)[:, :len(c.queries)]
-            cols = dict(zip(keys, rows.tolist()))
+        return rows
+
+    def _emit(self, outs: Sequence[Dict[str, Dict[str, float]]],
+              groups: Sequence[Sequence[str]],
+              classes: Sequence[M.DepthClass],
+              batches: Sequence[M.EvalBatch]) -> None:
+        """Measure each class's batch (:meth:`_measure_rows`) and fill
+        ``outs[i][qid]`` for each qid of ``groups[i]``; the groups lie end
+        to end on the query axis that the classes partition, and come out
+        in that order."""
+        rows = self._measure_rows(classes, batches)
+        keys = self.measure_keys
         with obs.span("repro.results"):
+            cols = dict(zip(keys, rows.tolist()))
             lo = 0
             for out, qids in zip(outs, groups):
                 for i, qid in enumerate(qids, lo):

@@ -402,17 +402,6 @@ def compute_measures(
     return _mask_queries(registry.apply_columns(s, measures), s)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def compute_measures_jit(batch, measures, relevance_level=1.0,
-                         judged_only=False):
-    # Lazy import: repro.kernels pulls in this module at its own import time.
-    # bucketing itself is dependency-free, so the in-body import is cheap and
-    # cycle-safe; the call runs at trace time only (once per signature).
-    from repro.kernels import bucketing
-    bucketing.record_trace("measure_core")
-    return compute_measures(batch, measures, relevance_level, judged_only)
-
-
 def compute_measures_topk(
     batch: EvalBatch,
     measures: Tuple[Tuple[str, Tuple[float, ...]], ...],
@@ -464,15 +453,6 @@ def compute_measures_topk(
     return _mask_queries(registry.apply_columns(s, measures), s)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def compute_measures_topk_jit(batch, measures, relevance_level=1.0,
-                              judged_only=False):
-    from repro.kernels import bucketing
-    bucketing.record_trace("measure_core_topk")
-    return compute_measures_topk(batch, measures, relevance_level,
-                                 judged_only)
-
-
 def pack_columns(columns: Dict[str, jax.Array], measures,
                  q: int) -> jax.Array:
     """The ``[Q]`` columns as one ``[K, Q]`` array, rows in
@@ -486,8 +466,11 @@ def pack_columns(columns: Dict[str, jax.Array], measures,
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def compute_measures_packed_jit(batch, measures, relevance_level=1.0,
                                 judged_only=False):
-    """:func:`compute_measures_jit`'s columns packed by
-    :func:`pack_columns` in the same program: one output to fetch."""
+    """:func:`compute_measures`'s columns packed by :func:`pack_columns`
+    in the same program: one output to fetch."""
+    # Lazy import: repro.kernels pulls in this module at its own import time.
+    # bucketing itself is dependency-free, so the in-body import is cheap and
+    # cycle-safe; the call runs at trace time only (once per signature).
     from repro.kernels import bucketing
     bucketing.record_trace("measure_core")
     return pack_columns(
@@ -498,13 +481,45 @@ def compute_measures_packed_jit(batch, measures, relevance_level=1.0,
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def compute_measures_topk_packed_jit(batch, measures, relevance_level=1.0,
                                      judged_only=False):
-    """:func:`compute_measures_topk_jit`'s columns packed by
+    """:func:`compute_measures_topk`'s columns packed by
     :func:`pack_columns` in the same program."""
     from repro.kernels import bucketing
     bucketing.record_trace("measure_core_topk")
     return pack_columns(
         compute_measures_topk(batch, measures, relevance_level, judged_only),
         measures, batch.query_mask.shape[0])
+
+
+def packed_core(topk: bool, mesh=None):
+    """The packed measure core of a depth class: the top-k route's
+    (``topk``) or the full sort's.
+
+    With a 1-D device ``mesh``, that core runs on every device over the
+    device's share of the query axis (``shard_map``: the top-k kernel's
+    ``pallas_call`` is not partitioned on its own), and the ``[K, q_pad]``
+    result is split over the devices on its query axis.  Each row is the
+    same program either way, since every measure is computed per query.
+    """
+    core = compute_measures_topk_packed_jit if topk else \
+        compute_measures_packed_jit
+    return core if mesh is None else _core_on_mesh(core, mesh)
+
+
+@functools.lru_cache(maxsize=None)
+def _core_on_mesh(core, mesh):
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed import shard_map
+
+    axis = mesh.axis_names[0]
+
+    def run(batch, measures, relevance_level=1.0, judged_only=False):
+        return shard_map(
+            lambda b: core(b, measures, relevance_level, judged_only),
+            mesh=mesh, in_specs=P(axis), out_specs=P(None, axis),
+            check_vma=False)(batch)
+
+    return jax.jit(run, static_argnums=(1, 2, 3))
 
 
 def aggregate(per_query: Dict[str, jax.Array], query_mask: jax.Array) -> Dict[str, jax.Array]:

@@ -78,31 +78,6 @@ def metric_update_run(
     return metric_update(state, batch, measure_names, relevance_level)
 
 
-def metric_update_cols(
-    state: MetricState,
-    per_query: Dict[str, jax.Array],
-    query_mask: jax.Array,
-) -> MetricState:
-    """Accumulate precomputed per-query measure vectors into a MetricState.
-
-    The fused-kernel/sharded counterpart of :func:`metric_update`: the caller
-    already holds per-query ``[Q]`` vectors (e.g. columns of
-    ``kernels.fused_measures``) and only needs the (sum, count) sufficient
-    statistics.  Every key in ``state`` except ``"__count"`` must be present
-    in ``per_query``; padded queries are excluded via ``query_mask``.  Pure
-    and shard_map-friendly — pair with
-    ``metric_finalize(state, axis_name=...)`` for the cross-device mean.
-    """
-    qm = query_mask.astype(jnp.float32)
-    new = dict(state)
-    for k in state:
-        if k == "__count":
-            continue
-        new[k] = state[k] + jnp.sum(per_query[k] * qm)
-    new["__count"] = state["__count"] + jnp.sum(qm)
-    return new
-
-
 def metric_finalize(state: MetricState, axis_name: str | None = None) -> Dict[str, jax.Array]:
     """Means over all queries; cross-device reduce if ``axis_name`` given."""
     count = state["__count"]

@@ -6,12 +6,13 @@ compared statistically.  Scoring them with K separate ``evaluate`` calls
 pays K measure-core dispatches and K rounds of padding; this module instead
 extends the serve layer's :func:`repro.core.evaluator.concat_run_buffers`
 coalescing to whole runs — the K runs are tokenized once each, stacked end
-to end on the query axis, and pushed through the jitted measure core in
-large fused batches.  Because every measure is computed row-independently,
-the resulting tensor is **bit-identical** to K independent
-:meth:`~repro.core.evaluator.RelevanceEvaluator.evaluate_buffer` calls
-(``tests/test_sweep.py`` asserts exact equality, including ragged
-per-query document counts padded by the bucketing layer).
+to end on the query axis, and measured in one call per group of runs, in
+the evaluator's depth classes (``evaluate_buffers``'s path).  Because every
+measure is computed row-independently, the resulting tensor equals K
+independent :meth:`~repro.core.evaluator.RelevanceEvaluator.evaluate_buffer`
+calls, and it is **bit-identical** to ``evaluate_buffers`` over the same
+runs (``tests/test_sweep.py`` asserts exact equality, including ragged
+per-query document counts and depth-bounded measures on the top-k route).
 
 The output :class:`SweepResult` holds the dense ``[K, Q, M]`` per-query
 tensor plus the aligned run/query/measure names, and hands ``[K, Q]``
@@ -151,7 +152,9 @@ def evaluate_sweep(
     the rows; default ``run_0 .. run_{K-1}``.
 
     ``backend`` is ``"single"``, ``"sharded"``, or ``"auto"`` (sharded iff
-    more than one device is visible) — values are identical either way.
+    more than one device is visible); both run the evaluator's depth
+    classes and measure cores, the sharded one split over the devices
+    (:class:`~repro.distributed.sharded_evaluator.ShardedEvaluator`).
     Work is dispatched in groups of whole runs bounded by the evaluator's
     ``chunk_queries``, so K can reach the hundreds without unbounded
     padding.
@@ -229,23 +232,18 @@ def evaluate_sweep(
         sev = ShardedEvaluator(ev)
 
     # Whole-run groups bounded by chunk_queries: consecutive sweep points
-    # share one padded dispatch, and a run larger than the chunk budget
-    # still goes through in one piece (same as evaluate_buffer).
+    # share one call, and a run larger than the chunk budget still goes
+    # through in one piece (same as evaluate_buffer).  Each group is
+    # measured as evaluate_buffers measures it: its depth classes, each
+    # routed to the full sort or the top-k kernel.
     group = max(1, ev.chunk_queries // max(nq, 1))
     for lo in range(0, k, group):
-        chunk = bufs[lo:lo + group]
-        big = concat_run_buffers(chunk) if len(chunk) > 1 else chunk[0]
+        big = concat_run_buffers(bufs[lo:lo + group])
         if sev is not None:
             rows = sev.evaluate_table([big])
         else:
-            batch = ev.batch_from_buffer(big)
-            per_query = M.compute_measures_jit(batch, ev.measures,
-                                               ev.relevance_level,
-                                               ev.judged_docs_only)
-            rows = np.stack(
-                [np.asarray(per_query[key])[:len(big.qids)] for key in keys],
-                axis=-1)
-        table[lo:lo + len(chunk)] = rows.reshape(len(chunk), nq, len(keys))
+            rows = ev._measure_rows(*ev._class_batches(big)).T
+        table[lo:lo + group] = rows.reshape(-1, nq, len(keys))
 
     return SweepResult(tuple(run_names), tuple(qids), tuple(keys), table)
 

@@ -1,17 +1,15 @@
 """Pallas TPU kernels for the framework's compute hot spots.
 
 * ``topk``            — blocked top-K over the document axis (ranking sort).
-* ``fused_measures``  — every trec_eval measure in one VMEM pass.
 * ``embedding_bag``   — scalar-prefetch gather + segment-sum (recsys tables).
 * ``bucketing``       — power-of-two shape classes + retrace accounting.
-* ``autotune``        — roofline-driven ``block_q`` selection.
 
 Each kernel ships with a pure-jnp oracle in ``ref.py`` and a jit'd wrapper
-in ``ops.py``.  Execution mode is backend-resolved at import
+in ``ops.py``.  Execution mode is backend-resolved at the first kernel call
 (``ops.INTERPRET``: compiled on TPU, interpret elsewhere; override with
 the ``REPRO_INTERPRET`` env var or per call) — see the ``ops`` module
 docstring for the full precedence rules.
 """
 
 from repro.kernels import bucketing  # noqa: F401  (dependency-free; first)
-from repro.kernels import autotune, ops, ref  # noqa: F401
+from repro.kernels import ops, ref  # noqa: F401

@@ -1,11 +1,12 @@
 """Power-of-two shape bucketing + trace-time recompile accounting.
 
-Every jit'd engine in this codebase (the measure core, the fused Pallas
-kernel, the sharded dispatch) compiles once per *shape signature*.  Left
-unbucketed, the serve layer's variable wave sizes — a coalesced batch of k
-requests has a query axis proportional to k — would trigger one XLA
-compile per distinct wave, re-introducing exactly the fixed per-call
-overhead the paper set out to kill.  This module centralizes the fix:
+Every jit'd engine in this codebase (the packed measure cores, on one
+device or on a mesh, and the Pallas kernels) compiles once per *shape
+signature*.  Left unbucketed, the serve layer's variable wave sizes — a
+coalesced batch of k requests has a query axis proportional to k — would
+trigger one XLA compile per distinct wave, re-introducing exactly the fixed
+per-call overhead the paper set out to kill.  This module centralizes the
+fix:
 
 * **padding classes** — batch extents are padded UP to the next power of
   two (``bucket_queries`` / ``bucket_docs``), so every possible extent in

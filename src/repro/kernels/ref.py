@@ -5,8 +5,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import measures as M
-
 
 def topk_ref(scores: jax.Array, k: int):
     """lax.top_k: same tie semantics (equal values → lower index first)."""
@@ -17,52 +15,6 @@ def topk_ref(scores: jax.Array, k: int):
     pad_v = jnp.full(scores.shape[:-1] + (k - d,), -jnp.inf, scores.dtype)
     pad_i = jnp.zeros(scores.shape[:-1] + (k - d,), jnp.int32)
     return jnp.concatenate([v, pad_v], -1), jnp.concatenate([i, pad_i], -1)
-
-
-def fused_measures_ref(rel_sorted, judged_sorted, scalars,
-                       relevance_level: float = 1.0):
-    """Column-for-column oracle of kernels.fused_measures via core.measures."""
-    from repro.kernels import fused_measures as FM
-
-    q, d = rel_sorted.shape
-    # Build a SortedBatch directly (input is already rank-ordered).
-    binrel = jnp.where(rel_sorted >= relevance_level, 1.0, 0.0)
-    s = M.SortedBatch(
-        rel=rel_sorted,
-        binrel=binrel,
-        judged=judged_sorted,
-        mask=jnp.ones_like(rel_sorted),
-        cum_rel=jnp.cumsum(binrel, axis=-1),
-        ideal_rel=jnp.zeros((q, 1), jnp.float32),  # idcg supplied via scalars
-        n_rel=scalars[:, 0],
-        n_judged_nonrel=scalars[:, 1],
-        n_ret=jnp.full((q,), float(d)),
-        query_mask=jnp.ones((q,), bool),
-    )
-    def safe_div(a, b):
-        return jnp.where(b > 0, a / jnp.maximum(b, 1e-30), 0.0)
-
-    cols = {
-        "map": M.average_precision(s),
-        "recip_rank": M.reciprocal_rank(s),
-        "ndcg": safe_div(M.dcg(s), scalars[:, 2]),
-        "bpref": M.bpref(s),
-        "num_rel_ret": s.cum_rel[:, -1],
-        "Rprec": M.r_precision(s),
-    }
-    for k in FM.CUTOFFS:
-        cols[f"P_{k}"] = M.precision_at(s, k)
-        cols[f"recall_{k}"] = M.recall_at(s, k)
-        cols[f"map_cut_{k}"] = M.map_cut(s, k)
-    for j, k in enumerate(FM.CUTOFFS):
-        cols[f"ndcg_cut_{k}"] = safe_div(M.dcg(s, k), scalars[:, 3 + j])
-    for k in FM.SUCCESS_CUTOFFS:
-        cols[f"success_{k}"] = M.success_at(s, k)
-    for k in FM.CUTOFFS:
-        cols[f"judged_{k}"] = M.judged_at(s, k)
-    cols[f"rbp_{FM.DEFAULT_RBP_P:.2f}"] = M.rbp(s, FM.DEFAULT_RBP_P)
-    out = jnp.stack([cols[name] for name in FM.COLUMNS], axis=-1)
-    return jnp.pad(out, ((0, 0), (0, FM.OUT_WIDTH - out.shape[-1])))
 
 
 def embedding_bag_ref(table, indices, segment_ids, n_bags, weights=None):

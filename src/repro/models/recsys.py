@@ -11,7 +11,7 @@ Shared anatomy: sparse embedding tables (the hot path, see ``embedding.py``)
     into the top-K kernel, never a loop).
 
 In-loop evaluation: serving paths return score tensors that feed directly
-into ``core.measures`` / ``kernels.fused_measures`` without leaving the
+into ``core.measures`` without leaving the
 device — the paper's in-process evaluation at pod scale.
 """
 

@@ -27,11 +27,10 @@ submissions delayed rather than the service growing an unbounded queue.
 Backend selection: per collection, ``"single"`` (the in-process evaluator),
 ``"sharded"`` (:class:`repro.distributed.ShardedEvaluator` over the shared
 device mesh), or ``"auto"`` (sharded exactly when >1 device is visible).
-Coalescing itself never changes values (either backend returns results
-bit-identical to its own per-request calls); between the two backends the
-usual fused-kernel caveat applies — exact on integer-representable
-cumulative sums, ~1 ulp on arbitrary float DCG sums (see
-``docs/ARCHITECTURE.md``).
+Both backends run the evaluator's own depth classes and measure cores; the
+sharded one only splits each class's query axis over the mesh.  Coalescing
+never changes values (either backend returns results bit-identical to its
+own per-request calls).
 """
 
 from __future__ import annotations
@@ -90,8 +89,7 @@ class _Collection:
 
         Called by the service's cache ``on_evict`` hook — on LRU eviction
         AND on re-registration of the same ``qrel_id``.  Registered run
-        buffers and the lazily built sharded evaluator (which pins a
-        compiled dispatch closure plus device-resident qrel slabs) are the
+        buffers and the lazily built sharded evaluator are the
         heavyweight references; clearing them here means a displaced
         collection's memory is reclaimable as soon as in-flight requests
         holding it finish, not whenever the GC finds the cycle.

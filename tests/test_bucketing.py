@@ -1,4 +1,4 @@
-"""Shape bucketing, the block_q autotuner, and the recompile-bound contract.
+"""Shape bucketing and the recompile-bound contract.
 
 The tentpole claim of the bucketing layer is operational: however many
 distinct raw batch extents a workload produces, the number of compiled jit
@@ -16,7 +16,7 @@ import threading
 import numpy as np
 import pytest
 
-from repro.kernels import autotune, bucketing
+from repro.kernels import bucketing
 
 
 # -- padding classes ---------------------------------------------------------
@@ -90,36 +90,6 @@ def test_trace_counters_thread_safe():
     for t in threads:
         t.join()
     assert bucketing.compile_count(name) == 8 * 200
-
-
-# -- block_q autotuner -------------------------------------------------------
-
-def test_block_q_bounds_and_pow2():
-    for q in (1, 7, 64, 1000, 4096):
-        for d in (8, 256, 4096, 1 << 16):
-            bq = autotune.block_q_for(q, d)
-            assert autotune.MIN_BLOCK_Q <= bq <= autotune.MAX_BLOCK_Q
-            assert bq & (bq - 1) == 0  # power of two
-
-
-def test_block_q_shrinks_with_wider_rows():
-    assert autotune.block_q_for(1024, 1 << 16) < \
-        autotune.block_q_for(1024, 1 << 10)
-
-
-def test_block_q_respects_vmem_budget():
-    d = 4096
-    bq = autotune.block_q_for(1024, d, vmem_bytes=1 << 20)
-    assert autotune.LIVE_TILES * bq * d * 4 <= (1 << 20) * \
-        autotune.VMEM_HEADROOM or bq == autotune.MIN_BLOCK_Q
-
-
-def test_block_q_clamps_to_small_batches():
-    assert autotune.block_q_for(4, 64) == autotune.MIN_BLOCK_Q
-
-
-def test_block_q_deterministic():
-    assert autotune.block_q_for(512, 512) == autotune.block_q_for(512, 512)
 
 
 # -- the recompile-bound contract on the real serve path --------------------

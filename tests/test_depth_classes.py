@@ -8,7 +8,7 @@ reference (``baselines/pure_eval.py``) within 1e-5, a uniform buffer must
 stay the one rectangle it always was, and the split must be built once per
 buffer and compile a closed set of signatures.  Each class's columns come
 back packed in one ``[K, q_pad]`` array, fetched in one copy, with the
-bits the per-key dict cores give.
+bits of the per-key columns.
 """
 
 import contextlib
@@ -233,19 +233,18 @@ def test_compiled_signatures_stay_in_the_closed_set():
     assert compiled < batches  # classes of one padding share one program
 
 
-def by_dict_cores(ev, bufs):
-    """Each buffer's results as the evaluator gave them before its columns
-    were packed: the buffers end to end, each class's dict core, one fetch
-    per key, put back in query order and split by buffer."""
+def by_class_cores(ev, bufs):
+    """Each buffer's results assembled key by key, apart from the
+    evaluator's row fetch: the buffers end to end, each class's packed
+    core, each key's row put back in query order and split by buffer."""
     both = concat_run_buffers(bufs)
     classes, batches = ev._class_batches(both)
     cols = {k: np.empty(len(both), np.float32) for k in ev.measure_keys}
     for c, batch in zip(classes, batches):
-        core = M.compute_measures_topk_jit if c.topk else M.compute_measures_jit
-        got = core(batch, ev.measures, ev.relevance_level,
-                   ev.judged_docs_only)
-        for k, col in cols.items():
-            col[c.queries] = np.asarray(got[k])[:len(c.queries)]
+        got = np.asarray(M.packed_core(c.topk)(
+            batch, ev.measures, ev.relevance_level, ev.judged_docs_only))
+        for row, (k, col) in zip(got, cols.items()):
+            col[c.queries] = row[:len(c.queries)]
     out, lo = [], 0
     for buf in bufs:
         out.append({q: {k: cols[k][i] for k in ev.measure_keys}
@@ -276,7 +275,7 @@ def test_packed_columns_are_the_dict_cores_bits(entry, measures, shape):
     assert any(c.topk for c in classes) == (measures == "bounded")
     other = rescore(run, 5)
     other_buf = ev.tokenize_run(other)
-    want = [by_dict_cores(ev, [b])[0] for b in (buf, other_buf)]
+    want = [by_class_cores(ev, [b])[0] for b in (buf, other_buf)]
     if entry == "evaluate_buffer":
         got = [ev.evaluate_buffer(buf),
                ev.evaluate_buffer(buf, scores=flat_scores(other))]
@@ -286,7 +285,7 @@ def test_packed_columns_are_the_dict_cores_bits(entry, measures, shape):
                                   [None, None, flat_scores(other)])
         assert got.pop(1) == {}
         # the buffers share one padded axis, so they share its programs
-        want = by_dict_cores(ev, [buf, other_buf])
+        want = by_class_cores(ev, [buf, other_buf])
     else:
         got = [ev.evaluate(run), ev.evaluate(other)]
     for g, w in zip(got, want):
@@ -303,10 +302,13 @@ def test_packed_cores_stack_the_dict_columns_in_key_order(route, measures):
     (only,) = classes_of(ev, buf)
     assert only.topk == (route == "top-k")
     batch = ev.batch_from_buffer(buf, topk_layout=only.topk)
-    dict_core, packed_core = {
-        "full-sort": (M.compute_measures_jit, M.compute_measures_packed_jit),
-        "top-k": (M.compute_measures_topk_jit,
+    dict_fn, packed_core = {
+        "full-sort": (M.compute_measures, M.compute_measures_packed_jit),
+        "top-k": (M.compute_measures_topk,
                   M.compute_measures_topk_packed_jit)}[route]
+    # the dict of columns in a program of its own (run op by op, XLA would
+    # fuse differently and move some values by an ulp or two)
+    dict_core = jax.jit(dict_fn, static_argnums=(1, 2, 3))
     args = (batch, ev.measures, ev.relevance_level, ev.judged_docs_only)
     columns, packed = dict_core(*args), np.asarray(packed_core(*args))
     assert packed.shape == (len(ev.measure_keys), only.q_pad)

@@ -53,7 +53,6 @@ def test_doc_code_blocks_run(path):
     "repro.client.sync",
     "repro.kernels.ops",
     "repro.kernels.bucketing",
-    "repro.kernels.autotune",
     "repro.obs",
     "repro.stats",
     "repro.stats.significance",

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import measures as M
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(7)
@@ -52,53 +51,6 @@ def test_topk_handles_short_rows():
     v, i = ops.topk(scores, 8)
     rv, ri = ref.topk_ref(scores, 8)
     np.testing.assert_allclose(np.asarray(v)[:, :5], np.asarray(rv)[:, :5])
-
-
-@pytest.mark.parametrize("q,d", [(3, 64), (8, 200), (13, 1024), (1, 4096)])
-def test_fused_measures_matches_ref(q, d):
-    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
-    rel = jnp.asarray(RNG.integers(0, 4, (q, d)).astype(np.float32))
-    judged = jnp.asarray(RNG.random((q, d)) < 0.6)
-    batch = M.batch_from_dense(scores, rel, judged=judged)
-    s = M.sort_batch(batch)
-    scal = ops.make_scalars(batch.n_rel, batch.n_judged_nonrel,
-                            batch.ideal_rel)
-    got = ops.fused_measures_cols(s.rel, s.judged, scal)
-    want = ref.fused_measures_ref(s.rel, s.judged, scal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-4, rtol=2e-4)
-
-
-@pytest.mark.parametrize("q,block_q", [(21, 8), (37, 16)])
-def test_fused_measures_multirow_blocks_ragged_q(q, block_q):
-    """Several grid steps of several rows each, plus a padded last block."""
-    d = 512
-    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
-    rel = jnp.asarray(RNG.integers(0, 3, (q, d)).astype(np.float32))
-    judged = jnp.asarray(RNG.random((q, d)) < 0.5)
-    batch = M.batch_from_dense(scores, rel, judged=judged)
-    s = M.sort_batch(batch)
-    scal = ops.make_scalars(batch.n_rel, batch.n_judged_nonrel,
-                            batch.ideal_rel)
-    got = ops.fused_measures_cols(s.rel, s.judged, scal, block_q=block_q)
-    want = ref.fused_measures_ref(s.rel, s.judged, scal)
-    assert got.shape == (q, 64)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-4, rtol=2e-4)
-
-
-def test_fused_evaluate_matches_measure_core():
-    q, d = 9, 300
-    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
-    rel = jnp.asarray(RNG.integers(0, 3, (q, d)).astype(np.float32))
-    batch = M.batch_from_dense(scores, rel)
-    fused = ops.evaluate_fused(batch)
-    parsed = M.parse_measures(("map", "ndcg", "ndcg_cut", "P", "recall",
-                               "recip_rank", "Rprec", "bpref", "success"))
-    want = M.compute_measures(batch, parsed)
-    for k, v in want.items():
-        np.testing.assert_allclose(np.asarray(fused[k]), np.asarray(v),
-                                   atol=2e-4, rtol=2e-4, err_msg=k)
 
 
 @pytest.mark.parametrize("v,e,b,l", [(30, 8, 4, 20), (100, 32, 10, 64),
@@ -183,42 +135,6 @@ def test_wrappers_read_global_at_call_time(monkeypatch):
     assert seen == [False, True, False]
 
 
-def test_fused_wrapper_reads_global_at_call_time(monkeypatch):
-    seen = []
-
-    def fake_fused(rel, judged, scal, block_q=None, relevance_level=1.0,
-                   interpret=None):
-        seen.append(interpret)
-        return jnp.zeros((rel.shape[0], 64), jnp.float32)
-
-    monkeypatch.setattr(ops._fm, "fused_measures", fake_fused)
-    rel = jnp.zeros((2, 8), jnp.float32)
-    scal = jnp.zeros((2, 16), jnp.float32)
-    monkeypatch.setattr(ops, "INTERPRET", True)
-    ops.fused_measures_cols(rel, rel, scal)
-    monkeypatch.setattr(ops, "INTERPRET", False)
-    ops.fused_measures_cols(rel, rel, scal)
-    ops.fused_measures_cols(rel, rel, scal, interpret=True)
-    assert seen == [True, False, True]
-
-
-def test_sharded_evaluator_snapshots_interpret(monkeypatch):
-    """ShardedEvaluator captures the mode at construction — documented caveat."""
-    from repro.core import RelevanceEvaluator
-    from repro.distributed.sharded_evaluator import ShardedEvaluator
-
-    ev = RelevanceEvaluator({"q1": {"d1": 1}}, ("map",))
-    live = ops.interpret_mode()
-    se = ShardedEvaluator(ev)
-    assert se.interpret == live
-    # flipping the global does NOT change an existing instance...
-    monkeypatch.setattr(ops, "INTERPRET", not live)
-    assert se.interpret == live
-    # ...but a rebuilt one (or an explicit arg) picks the new mode up
-    assert ShardedEvaluator(ev).interpret == (not live)
-    assert ShardedEvaluator(ev, interpret=live).interpret == live
-
-
 # -- compiled-vs-interpret conformance gate ----------------------------------
 #
 # On a TPU host the resolved default is the COMPILED path and this gate
@@ -237,19 +153,6 @@ def _assert_mode_parity(got, want, what):
                                    err_msg=what)
 
 
-def test_parity_fused_measures_default_vs_interpret():
-    q, d = 7, 200
-    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
-    rel = jnp.asarray(RNG.integers(0, 3, (q, d)).astype(np.float32))
-    batch = M.batch_from_dense(scores, rel)
-    s = M.sort_batch(batch)
-    scal = ops.make_scalars(batch.n_rel, batch.n_judged_nonrel,
-                            batch.ideal_rel)
-    got = ops.fused_measures_cols(s.rel, s.judged, scal)  # resolved default
-    want = ops.fused_measures_cols(s.rel, s.judged, scal, interpret=True)
-    _assert_mode_parity(got, want, "fused_measures default vs interpret")
-
-
 def test_parity_topk_default_vs_interpret():
     scores = jnp.asarray(RNG.standard_normal((3, 1000)).astype(np.float32))
     v, i = ops.topk(scores, 50)
@@ -265,19 +168,3 @@ def test_parity_embedding_bag_default_vs_interpret():
     got = ops.embedding_bag(table, idx, seg, 6)
     want = ops.embedding_bag(table, idx, seg, 6, interpret=True)
     _assert_mode_parity(got, want, "embedding_bag default vs interpret")
-
-
-def test_explicit_block_q_matches_autotuned():
-    """block_q only tiles the VMEM walk; results are block-size invariant."""
-    q, d = 13, 128
-    scores = jnp.asarray(RNG.standard_normal((q, d)).astype(np.float32))
-    rel = jnp.asarray(RNG.integers(0, 2, (q, d)).astype(np.float32))
-    batch = M.batch_from_dense(scores, rel)
-    s = M.sort_batch(batch)
-    scal = ops.make_scalars(batch.n_rel, batch.n_judged_nonrel,
-                            batch.ideal_rel)
-    auto = ops.fused_measures_cols(s.rel, s.judged, scal)
-    for bq in (8, 16, 128):
-        manual = ops.fused_measures_cols(s.rel, s.judged, scal, block_q=bq)
-        np.testing.assert_array_equal(np.asarray(auto), np.asarray(manual),
-                                      err_msg=f"block_q={bq}")

@@ -6,8 +6,7 @@ table and per-run :meth:`RelevanceEvaluator.evaluate_buffer` calls —
 including ragged per-query document counts, chunked dispatch groups, and
 randomized shapes (hypothesis when installed, a seeded sweep otherwise).
 The sharded backend is held to the same standard against its own
-``evaluate_buffer`` (the fused kernel's float-gain reductions drift ~1 ulp
-from the single-device core, so cross-backend comparison is 1e-6).
+``evaluate_buffer`` and against the single-device sweep.
 """
 
 import numpy as np
@@ -103,10 +102,38 @@ def test_sharded_backend_matches_sharded_evaluate_buffer():
             for mi, key in enumerate(result.measure_keys):
                 assert result.table[ki, qi, mi] == \
                     res.per_query[qid][key], (ki, qid, key)
-    # ...and within float32 noise of the single-device sweep (the fused
-    # kernel's gain reductions associate differently: ~1 ulp on ndcg)
+    # ...and the single-device sweep's bits: the same classes and cores
     single = evaluate_sweep(ev, runs)
-    assert np.allclose(result.table, single.table, atol=1e-6)
+    assert np.array_equal(result.table, single.table)
+
+
+@pytest.mark.parametrize("backend,measures", [
+    ("single", ("nDCG@7", "P@9")), ("sharded", ("nDCG@9", "P@7"))],
+    ids=["single", "sharded"])
+def test_ragged_bounded_sweep_is_evaluate_buffers_bits(backend, measures):
+    """Lists 1-1,100 deep and depth-bounded measures: the sweep measures
+    its runs end to end in depth classes, the wide ones on the top-k
+    kernel, exactly as ``evaluate_buffers`` does over the same runs.  A
+    measure pair no other test uses keys fresh compiles to count."""
+    from repro.kernels import bucketing
+
+    rng = np.random.default_rng(11)
+    lengths = [1, 5, 60, 300, 700, 1100]
+    qrel = {f"q{q}": {f"d{j}": int(g) for j, g in enumerate(
+        rng.choice(3, n, p=(0.6, 0.3, 0.1)))} for q, n in enumerate(lengths)}
+    runs = [{q: {d: float(s) for d, s in zip(docs, np.round(
+        2 * rng.normal(size=len(docs))) / 2)} for q, docs in qrel.items()}
+        for _ in range(3)]
+    ev = RelevanceEvaluator(qrel, measures)
+    before = bucketing.compile_count("measure_core_topk")
+    result = evaluate_sweep(ev, runs, backend=backend)
+    assert bucketing.compile_count("measure_core_topk") > before
+    want = ev.evaluate_buffers([ev.tokenize_run(r) for r in runs])
+    for ki, per_query in enumerate(want):
+        for qi, qid in enumerate(result.qids):
+            for mi, key in enumerate(result.measure_keys):
+                assert result.table[ki, qi, mi] == per_query[qid][key], \
+                    (ki, qid, key)
 
 
 # -- randomized shapes: hypothesis when available, seeded sweep always -------

@@ -3,8 +3,9 @@
 The TPU compiler is installed wherever JAX is, and it compiles for a chip
 that is described rather than attached.  Mosaic refuses here exactly what
 it would refuse on the chip (unaligned block shapes, primitives without a
-TPU lowering), so these compiles guard both Pallas kernels and the measure
-core at the sizes users run, at no chip time.
+TPU lowering), so these compiles guard the top-k Pallas kernel and the
+packed measure cores, on one chip and over the four chips of a mesh, at
+the sizes users run, at no chip time.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every pytest worker
@@ -13,15 +14,17 @@ imports this file.
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.core import measures as M
-from repro.kernels import autotune, fused_measures, ops, topk
+from repro.kernels import ops, topk
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     import os
 
     from jax.experimental import topologies
@@ -38,9 +41,14 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -57,16 +65,6 @@ def _batch_spec(sharding, q, d, j):
         query_mask=_spec(sharding, (q,), jnp.bool_))
 
 
-def test_fused_measures_compiles_for_v5e(one_chip):
-    q, d = 256, 1024
-    block_q = autotune.block_q_for(q, d)
-    args = (_spec(one_chip, (q, d)), _spec(one_chip, (q, d)),
-            _spec(one_chip, (q, 16)))
-    compiled = jax.jit(lambda r, j, s: fused_measures.fused_measures(
-        r, j, s, block_q=block_q, interpret=False)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 @pytest.mark.parametrize("k", [10, 1000])
 def test_topk_compiles_for_v5e(one_chip, k):
     compiled = jax.jit(lambda s: topk.topk(s, k, interpret=False)).lower(
@@ -77,7 +75,7 @@ def test_topk_compiles_for_v5e(one_chip, k):
 def test_measure_core_compiles_for_v5e(one_chip):
     parsed = M.parse_measures(("map", "bpref", "ndcg", "Rprec", "recip_rank",
                                "P", "recall", "ndcg_cut"))
-    compiled = M.compute_measures_jit.lower(
+    compiled = M.compute_measures_packed_jit.lower(
         _batch_spec(one_chip, 256, 1024, 2048), parsed, 1.0,
         False).compile()
     # the full-sort core is plain XLA: no kernel expected
@@ -89,7 +87,7 @@ def test_topk_measure_core_compiles_for_v5e(one_chip, monkeypatch):
     """The depth-bounded route at the MS MARCO dev shape runs the kernel."""
     monkeypatch.setattr(ops, "INTERPRET", False)
     parsed = M.parse_measures(("nDCG@10", "P@10"))
-    compiled = M.compute_measures_topk_jit.lower(
+    compiled = M.compute_measures_topk_packed_jit.lower(
         _batch_spec(one_chip, 8192, 1024, 8), parsed, 1.0, False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
@@ -105,9 +103,7 @@ def test_depth_class_measure_cores_compile_for_v5e(one_chip, monkeypatch, q,
     narrow ones on the full sort, the wide ones on the top-k kernel."""
     monkeypatch.setattr(ops, "INTERPRET", False)
     parsed = M.parse_measures(("nDCG@5", "nDCG@10"))
-    core = (M.compute_measures_topk_jit if topk_route
-            else M.compute_measures_jit)
-    compiled = core.lower(_batch_spec(one_chip, q, d, d), parsed, 1.0,
+    compiled = M.packed_core(topk_route).lower(_batch_spec(one_chip, q, d, d), parsed, 1.0,
                           False).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == topk_route
 
@@ -132,6 +128,28 @@ def test_packed_measure_cores_compile_for_v5e(one_chip, monkeypatch, q, d, j,
     lowered = core.lower(_batch_spec(one_chip, q, d, j), parsed, 1.0, False)
     assert lowered.out_info.shape == (len(M.measure_keys(measures)), q)
     assert lowered.out_info.dtype == jnp.float32
+    compiled = lowered.compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == topk_route
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("q,d,j,measures,topk_route", [
+    (256, 1024, 2048, ROBUST04, False),
+    (8192, 1024, 8, ("nDCG@10", "P@10"), True)],
+    ids=["full-sort", "top-k"])
+def test_sharded_packed_cores_compile_for_v5e_2x2(v5e_2x2, monkeypatch, q, d,
+                                                  j, measures, topk_route):
+    """``ShardedEvaluator``'s programs: each packed core under
+    ``shard_map`` over the four chips of a 2x2 mesh, every batch split on
+    its query axis, Robust04's full sort and the MS MARCO dev shape's
+    top-k kernel, which Mosaic lowers for each chip's share."""
+    monkeypatch.setattr(ops, "INTERPRET", False)
+    mesh = Mesh(np.array(v5e_2x2.devices[:4]), ("data",))
+    sharding = NamedSharding(mesh, PartitionSpec("data"))
+    parsed = M.parse_measures(measures)
+    lowered = M.packed_core(topk_route, mesh).lower(
+        _batch_spec(sharding, q, d, j), parsed, 1.0, False)
+    assert lowered.out_info.shape == (len(M.measure_keys(measures)), q)
     compiled = lowered.compile()
     assert ("tpu_custom_call" in compiled.as_text()) == topk_route
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
