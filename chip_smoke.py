@@ -389,7 +389,8 @@ def sharded_run(coll, measures, reference, mesh=None):
         sev = ShardedEvaluator(ev, mesh=mesh)
         got = sev.evaluate_buffer(buf).per_query
     single = ev.evaluate_buffer(buf)
-    classes, batches = ev._class_batches(buf, q_multiple=sev.n_shards)
+    layout, batches = ev._class_batches(buf, q_multiple=sev.n_shards)
+    classes = layout.classes
     texts = [M.packed_core(c.topk, sev.mesh).lower(
         b, ev.measures, ev.relevance_level,
         ev.judged_docs_only).compile().as_text()

@@ -174,7 +174,9 @@ class RelevanceEvaluator:
     relies on this to run backend calls on executor threads).  The one lazy
     mutation — the seed reference-densifier state — is built under a lock.
     A buffer's cached padded layout needs none: threads that race on a
-    new buffer each build an identical layout, and the last one kept wins.
+    new buffer each build an identical layout, and the last one kept wins;
+    so do the copies of its static slabs that a placement keeps on the
+    device.
     """
 
     def __init__(
@@ -341,9 +343,10 @@ class RelevanceEvaluator:
                             batch.ideal_rel.shape[1], False,
                             int(np.count_nonzero(batch.mask)))]
                         batches = [batch]
-                    else:
-                        classes, batches = self._class_batches(
+                    else:  # a layout of this call alone: nothing kept
+                        layout, batches = self._class_batches(
                             self._tokenize_chunk(run, chunk))
+                        classes = layout.classes
                 self._emit([out], [chunk], classes, batches)
         return out
 
@@ -530,8 +533,9 @@ class RelevanceEvaluator:
         return layout.batches(buf.scores)[0]
 
     def _class_batches(self, buf: RunBuffer, q_multiple: int = 1):
-        """``(classes, batches)``: one padded batch per depth class of
-        ``buf``, each routed on its own padded depth.
+        """``(layout, batches)``: ``buf``'s depth-class
+        :class:`core.measures.FlatLayout` and one padded batch per class
+        (``layout.classes``), each routed on its own padded depth.
 
         A query's class is the padding class of its own list length
         (``bucketing.bucket_docs``), so a ragged buffer is not padded to
@@ -546,7 +550,7 @@ class RelevanceEvaluator:
             raise ValueError("buffer has no scores; pass scores=")
         layout = self._layout(buf, ("depth classes", q_multiple),
                               lambda: self._depth_classes(buf, q_multiple))
-        return layout.classes, layout.batches(buf.scores)
+        return layout, layout.batches(buf.scores)
 
     def _layout(self, buf: RunBuffer, key, classes) -> M.FlatLayout:
         """The layout ``buf`` holds under ``key``, or one built from
@@ -635,8 +639,8 @@ class RelevanceEvaluator:
             with obs.span("repro.ingest"):
                 if scores is not None:
                     buf = buf.with_scores(scores)
-                classes, batches = self._class_batches(buf)
-            self._emit([out], [buf.qids], classes, batches)
+                layout, batches = self._class_batches(buf)
+            self._emit([out], [buf.qids], layout.classes, batches, layout)
         return out
 
     def evaluate_buffers(
@@ -676,11 +680,12 @@ class RelevanceEvaluator:
                             for b, s in zip(bufs, scores_list)]
                 nonempty = [b for b in bufs if len(b)]
                 if nonempty:
-                    classes, batches = self._class_batches(
+                    layout, batches = self._class_batches(
                         concat_run_buffers(nonempty))
             results: List[Dict[str, Dict[str, float]]] = [{} for _ in bufs]
             if nonempty:
-                self._emit(results, [b.qids for b in bufs], classes, batches)
+                self._emit(results, [b.qids for b in bufs], layout.classes,
+                           batches, layout)
         return results
 
     def evaluate_sharded(self, run_or_buffer, mesh=None):
@@ -912,18 +917,23 @@ class RelevanceEvaluator:
     # -- output ---------------------------------------------------------------
 
     def _measure_rows(self, classes: Sequence[M.DepthClass],
-                      batches: Sequence[M.EvalBatch],
-                      mesh=None) -> np.ndarray:
+                      batches: Sequence[M.EvalBatch], mesh=None,
+                      layout: Optional[M.FlatLayout] = None) -> np.ndarray:
         """The host ``[K, nq]`` float32 measure rows, in ``measure_keys``
         order, of the ``nq`` queries that ``classes`` partition.
 
-        Every batch goes to the device in one copy, and every class's
-        measure core is launched before one wait.  The copy and the measure
-        cores each end on the device (``block_until_ready``), with or
-        without a profiler session, so a traced call runs the same schedule
-        as an untraced one.  Each class's core returns its columns packed
-        in one ``[K, q_pad]`` array; every class's copy to the host is
-        started before any is read.
+        What goes to the device goes in one copy, and every class's
+        measure core is launched before one wait.  ``layout``, where
+        given, is the layout that ``classes`` and ``batches`` come from:
+        its first call at a placement sends every leaf and keeps the
+        static ones on it (``FlatLayout.device``), and each later call
+        there sends the scores slabs alone.  Without it every leaf is
+        sent, as for a batch built for this call alone.  The copy and the
+        measure cores each end on the device (``block_until_ready``), with
+        or without a profiler session, so a traced call runs the same
+        schedule as an untraced one.  Each class's core returns its
+        columns packed in one ``[K, q_pad]`` array; every class's copy to
+        the host is started before any is read.
 
         ``mesh`` is a 1-D device mesh to split every batch's query axis
         over, given only by
@@ -935,11 +945,27 @@ class RelevanceEvaluator:
         placement = None if mesh is None else NamedSharding(
             mesh, PartitionSpec(mesh.axis_names[0]))
         with obs.span("repro.transfer"):
+            static = None if layout is None else layout.device.get(placement)
             for c in classes:
                 obs.count("repro.batch.rows", c.docs)
                 obs.count("repro.batch.cells", c.q_pad * c.d_pad)
-            batches = jax.block_until_ready(
-                jax.device_put(list(batches), placement))
+                obs.mark("repro.transfer.static_upload" if static is None
+                         else "repro.transfer.static_hit")
+            if static is None:
+                obs.count("repro.transfer.bytes",
+                          sum(a.nbytes for b in batches for a in b))
+                batches = jax.block_until_ready(
+                    jax.device_put(list(batches), placement))
+                if layout is not None:
+                    layout.device[placement] = tuple(
+                        b._replace(scores=None) for b in batches)
+            else:
+                obs.count("repro.transfer.bytes",
+                          sum(b.scores.nbytes for b in batches))
+                scores = jax.block_until_ready(jax.device_put(
+                    [b.scores for b in batches], placement))
+                batches = [b._replace(scores=s)
+                           for b, s in zip(static, scores)]
         with obs.span("repro.compute"):
             packed = jax.block_until_ready([
                 M.packed_core(c.topk, mesh)(
@@ -959,12 +985,13 @@ class RelevanceEvaluator:
     def _emit(self, outs: Sequence[Dict[str, Dict[str, float]]],
               groups: Sequence[Sequence[str]],
               classes: Sequence[M.DepthClass],
-              batches: Sequence[M.EvalBatch]) -> None:
-        """Measure each class's batch (:meth:`_measure_rows`) and fill
-        ``outs[i][qid]`` for each qid of ``groups[i]``; the groups lie end
-        to end on the query axis that the classes partition, and come out
-        in that order."""
-        rows = self._measure_rows(classes, batches)
+              batches: Sequence[M.EvalBatch],
+              layout: Optional[M.FlatLayout] = None) -> None:
+        """Measure each class's batch (:meth:`_measure_rows`, with the
+        ``layout`` they come from) and fill ``outs[i][qid]`` for each qid
+        of ``groups[i]``; the groups lie end to end on the query axis that
+        the classes partition, and come out in that order."""
+        rows = self._measure_rows(classes, batches, layout=layout)
         keys = self.measure_keys
         with obs.span("repro.results"):
             cols = dict(zip(keys, rows.tolist()))

@@ -26,7 +26,7 @@ are excluded by the aggregation helpers.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, NamedTuple, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Sequence, Tuple
 
 import numpy as np
 
@@ -573,6 +573,12 @@ class FlatLayout(NamedTuple):
     ``None`` where there is one class and the flat order is its query-major
     ``rows × depth`` block, so the scores are one reshape copy.  The static
     slabs are read-only: every batch shares them.
+
+    ``device`` holds the static slabs' copies on the device, one tuple of
+    batches (scores ``None``) per placement: ``None`` for the default
+    device, or a mesh's ``NamedSharding``.  The first call at a placement
+    sends them (``RelevanceEvaluator._measure_rows``); later calls send
+    the scores alone.  They live and die with the layout.
     """
 
     classes: Tuple[DepthClass, ...]
@@ -580,6 +586,7 @@ class FlatLayout(NamedTuple):
     dest: np.ndarray | None  # [n] intp
     rows: int
     depth: int
+    device: Dict[Any, Tuple[EvalBatch, ...]]  # placement -> static slabs
 
     def batches(self, scores: np.ndarray) -> List[EvalBatch]:
         """The padded batch of each class for these flat scores."""
@@ -696,7 +703,7 @@ def flat_layout(
     for a in (*(f for s in static for f in s[1:]), dest):
         if a is not None:
             a.flags.writeable = False
-    return FlatLayout(classes, tuple(static), dest, n_queries, d)
+    return FlatLayout(classes, tuple(static), dest, n_queries, d, {})
 
 
 # ---------------------------------------------------------------------------

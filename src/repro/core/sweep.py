@@ -242,7 +242,9 @@ def evaluate_sweep(
         if sev is not None:
             rows = sev.evaluate_table([big])
         else:
-            rows = ev._measure_rows(*ev._class_batches(big)).T
+            layout, batches = ev._class_batches(big)
+            rows = ev._measure_rows(layout.classes, batches,
+                                    layout=layout).T
         table[lo:lo + group] = rows.reshape(-1, nq, len(keys))
 
     return SweepResult(tuple(run_names), tuple(qids), tuple(keys), table)

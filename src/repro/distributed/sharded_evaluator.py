@@ -7,7 +7,8 @@ Python.  It runs the evaluator's own path; only the placement differs:
     RunBuffer ──_class_batches(q_multiple=mesh size)──► one EvalBatch per
         depth class, its query axis padded to divide over the mesh
     each batch ──device_put(NamedSharding(mesh, P(axis)))──► one shard per
-        device
+        device (a buffer's static slabs once, kept with its layout; then
+        the scores alone)
     each class's packed core (full sort or top-k) ──shard_map──► [K, q_pad]
         rows, split over the devices on the query axis
     rows ──one host copy per class──► per-query dicts; aggregates on the host
@@ -125,9 +126,10 @@ class ShardedEvaluator:
         ev = self.evaluator
         with obs.span("repro.evaluate"):
             with obs.span("repro.ingest"):
-                classes, batches = ev._class_batches(
+                layout, batches = ev._class_batches(
                     concat_run_buffers(bufs), q_multiple=self.n_shards)
-            return ev._measure_rows(classes, batches, mesh=self.mesh)
+            return ev._measure_rows(layout.classes, batches, mesh=self.mesh,
+                                    layout=layout)
 
     def _aggregates(self, rows: np.ndarray) -> Dict[str, float]:
         """Corpus means of ``[K, n]`` rows: float32 sum / n per measure,

@@ -8,10 +8,13 @@ reference (``baselines/pure_eval.py``) within 1e-5, a uniform buffer must
 stay the one rectangle it always was, and the split must be built once per
 buffer and compile a closed set of signatures.  Each class's columns come
 back packed in one ``[K, q_pad]`` array, fetched in one copy, with the
-bits of the per-key columns.
+bits of the per-key columns.  A buffer's static slabs go to the device
+on its first call and stay there; later calls send the scores alone.
 """
 
 import contextlib
+import gc
+import weakref
 
 import jax
 import numpy as np
@@ -96,8 +99,7 @@ def assert_agrees(got, want, keys):
 
 
 def classes_of(ev, buf):
-    classes, _ = ev._class_batches(buf)
-    return classes
+    return ev._class_batches(buf)[0].classes
 
 
 @contextlib.contextmanager
@@ -238,7 +240,8 @@ def by_class_cores(ev, bufs):
     evaluator's row fetch: the buffers end to end, each class's packed
     core, each key's row put back in query order and split by buffer."""
     both = concat_run_buffers(bufs)
-    classes, batches = ev._class_batches(both)
+    layout, batches = ev._class_batches(both)
+    classes = layout.classes
     cols = {k: np.empty(len(both), np.float32) for k in ev.measure_keys}
     for c, batch in zip(classes, batches):
         got = np.asarray(M.packed_core(c.topk)(
@@ -342,3 +345,125 @@ def test_one_fetch_copy_per_class_per_call(entry, shape, tmp_path):
         4 * len(ev.measure_keys) * c.q_pad for c in classes] * 3
     assert {recs[r.parent].name for r in copies} == {"repro.fetch"}
     assert [r.name for r in recs].count("repro.evaluate") == 3
+
+
+def transfer_records(recs):
+    """Per ``repro.transfer`` span: its static marks and the bytes sent."""
+    out = []
+    for i, r in enumerate(recs):
+        if r.name == "repro.transfer":
+            kids = [k for k in recs if k.parent == i]
+            out.append(([k.name for k in kids
+                         if k.name.startswith("repro.transfer.static_")],
+                        [k.value for k in kids
+                         if k.name == "repro.transfer.bytes"]))
+    return out
+
+
+def counted_puts(monkeypatch):
+    """Every ``jax.device_put`` from here on: the leaves each one sent."""
+    puts, put = [], jax.device_put
+
+    def counting(x, *a, **k):
+        puts.append(jax.tree.leaves(x))
+        return put(x, *a, **k)
+
+    monkeypatch.setattr(jax, "device_put", counting)
+    return puts
+
+
+def leaf_bytes(batch):
+    return sum(a.nbytes for a in batch)
+
+
+@pytest.mark.parametrize("measures", [UNBOUNDED, BOUNDED],
+                         ids=["unbounded", "bounded"])
+@pytest.mark.parametrize("shape", CASES)
+def test_static_slabs_go_to_the_device_once_per_buffer(shape, measures,
+                                                       tmp_path,
+                                                       monkeypatch):
+    qrel, run = CASES[shape](8)
+    ev = RelevanceEvaluator(qrel, measures)
+    ev.evaluate_buffer(ev.tokenize_run(run))  # compiles outside the session
+    buf = ev.tokenize_run(run)
+    other = rescore(run, 9)
+    puts = counted_puts(monkeypatch)
+    with profiled(tmp_path):
+        got = [ev.evaluate_buffer(buf),
+               ev.evaluate_buffer(buf, scores=flat_scores(other))]
+    layout = buf.layout[0][1]
+    classes = layout.classes
+    assert (len(classes) == 1) == (shape == "one-class")
+    assert any(c.topk for c in classes) == (measures is BOUNDED)
+    host = layout.batches(flat_scores(other))
+    scores_bytes = sum(4 * c.q_pad * c.d_pad for c in classes)
+    # the first call sends every leaf, the second the scores slabs alone
+    assert transfer_records(obs.records()) == [
+        (["repro.transfer.static_upload"] * len(classes),
+         [sum(leaf_bytes(b) for b in host)]),
+        (["repro.transfer.static_hit"] * len(classes), [scores_bytes])]
+    assert [len(p) for p in puts] == [9 * len(classes), len(classes)]
+    assert [p.shape for p in puts[1]] == [(c.q_pad, c.d_pad)
+                                          for c in classes]
+    # the device copy is the host's static slabs, kept for the default
+    # placement and sent no more
+    (static,) = layout.device.values()
+    assert list(layout.device) == [None]
+    for dev, want in zip(static, layout.static):
+        assert dev.scores is None
+        for field in M.EvalBatch._fields[1:]:
+            np.testing.assert_array_equal(np.asarray(getattr(dev, field)),
+                                          getattr(want, field),
+                                          err_msg=field)
+    # the same bits as buffers seen for the first time
+    for g, r in zip(got, (run, other)):
+        assert_same_bits(g, ev.evaluate_buffer(ev.tokenize_run(r)),
+                         ev.measure_keys)
+
+
+def test_a_new_layout_drops_the_old_device_copy(tmp_path):
+    qrel, run = ragged_case(10)
+    ev = RelevanceEvaluator(qrel, BOUNDED)
+    buf = ev.tokenize_run(run)
+    ev.evaluate_buffer(buf)
+    old = buf.layout[0][1]
+    gone = [weakref.ref(a) for b in old.device[None] for a in b[1:]]
+    # a one-rectangle batch is another layout: it replaces the classes'
+    ev.batch_from_buffer(buf)
+    assert buf.layout[0][1] is not old and not buf.layout[0][1].device
+    del old
+    gc.collect()
+    assert all(r() is None for r in gone)
+    with profiled(tmp_path):
+        got = ev.evaluate_buffer(buf)
+    classes = buf.layout[0][1].classes
+    assert [m for m, _ in transfer_records(obs.records())] == [
+        ["repro.transfer.static_upload"] * len(classes)]
+    assert list(buf.layout[0][1].device) == [None]
+    assert_same_bits(got, ev.evaluate_buffer(ev.tokenize_run(run)),
+                     ev.measure_keys)
+
+
+@pytest.mark.parametrize("entry", ["evaluate", "evaluate_buffers"])
+def test_layouts_of_one_call_send_every_leaf_in_one_put(entry, tmp_path,
+                                                        monkeypatch):
+    qrel, run = ragged_case(11)
+    ev = RelevanceEvaluator(qrel, BOUNDED)
+    bufs = [ev.tokenize_run(run), ev.tokenize_run(rescore(run, 12))]
+    if entry == "evaluate":
+        call, stacked = (lambda: [ev.evaluate(run)]), bufs[0]
+    else:  # a coalesced flush stacks its buffers into a new one each call
+        call = lambda: ev.evaluate_buffers(bufs)  # noqa: E731
+        stacked = concat_run_buffers(bufs)
+    want = call()  # compiles outside the session
+    layout, host = ev._class_batches(stacked)
+    puts = counted_puts(monkeypatch)
+    with profiled(tmp_path):
+        got = [call(), call()]
+    assert transfer_records(obs.records()) == [
+        (["repro.transfer.static_upload"] * len(layout.classes),
+         [sum(leaf_bytes(b) for b in host)])] * 2
+    assert [len(p) for p in puts] == [9 * len(layout.classes)] * 2
+    for g in got:
+        for a, b in zip(g, want):
+            assert_same_bits(a, b, ev.measure_keys)

@@ -218,7 +218,8 @@ def mesh_results(devices):
                                     judged_docs_only=judged_only)
             buf = ev.tokenize_run(run)
             sev = ShardedEvaluator(ev)
-            classes, _ = ev._class_batches(buf, q_multiple=sev.n_shards)
+            classes = ev._class_batches(
+                buf, q_multiple=sev.n_shards)[0].classes
             out[f"{route}/{judged_only}"] = {
                 "n_shards": sev.n_shards,
                 "topk": [c.topk for c in classes],
@@ -333,3 +334,38 @@ def test_sharded_aggregates_are_the_host_mean_of_the_rows(entry):
         want = ev.evaluate_buffer(buf)
         assert res.per_query == want
         assert res.aggregates == host_aggregates(want)
+
+
+def test_mesh_and_default_placement_each_keep_their_static_slabs():
+    """One buffer measured on the 1-device mesh and on the default device:
+    one layout (both pad the query axis alike), one device copy of its
+    static slabs per placement, and ``evaluate_buffer``'s values on both,
+    on the first call at each placement and on the calls that reuse it."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.core import RelevanceEvaluator
+    from repro.distributed import ShardedEvaluator
+
+    measures, lengths = ROUTES["mixed"]
+    qrel, run = route_collection(lengths, seed=2)
+    ev = RelevanceEvaluator(qrel, measures)
+    sev = ShardedEvaluator(ev)
+    assert sev.n_shards == 1
+    buf = ev.tokenize_run(run)
+    rng = np.random.default_rng(2)
+    for k in range(3):
+        fresh = None if k == 0 else np.round(
+            2 * rng.normal(size=buf.qidx.shape[0])).astype(np.float32) / 2
+        want = ev.evaluate_buffer(ev.tokenize_run(run), scores=fresh)
+        assert_same_values(sev.evaluate_buffer(buf, scores=fresh).per_query,
+                           want)
+        assert_same_values(ev.evaluate_buffer(buf, scores=fresh), want)
+    layout = buf.layout[0][1]
+    mesh = NamedSharding(sev.mesh, PartitionSpec(sev.mesh.axis_names[0]))
+    assert set(layout.device) == {None, mesh}
+    assert len(layout.device[None]) == len(layout.classes) == 5
+    for on_mesh, default in zip(layout.device[mesh], layout.device[None]):
+        assert on_mesh.rel.sharding == mesh
+        assert on_mesh.rel is not default.rel
+        np.testing.assert_array_equal(np.asarray(on_mesh.rel),
+                                      np.asarray(default.rel))
