@@ -723,26 +723,39 @@ class RelevanceEvaluator:
         integer work.  Only runs containing out-of-vocabulary docnos (absent
         from the qrels) fall back to the exact string pipeline, because OOV
         tie-breaks need real lexicographic comparisons.
+
+        While the profiler records, the work is the span
+        ``repro.tokenize``, with three counts per chunk:
+        ``repro.tokenize.rows`` (its documents), ``repro.tokenize.oov``
+        (those absent from the qrels) and ``repro.tokenize.strings`` (those
+        that went through the string pipeline).
         """
-        doc_maps = [run[q] for q in qids]
-        nq = len(qids)
-        counts = np.fromiter(map(len, doc_maps), dtype=np.int64, count=nq)
-        total = int(counts.sum())
-        if not total:
-            return self._make_buffer(list(qids), counts,
-                                     np.empty(0, dtype="U1"),
-                                     np.empty(0, dtype=np.float32))
-        tokens = np.fromiter(
-            map(self._tok.get, chain.from_iterable(doc_maps), repeat(-1)),
-            dtype=np.int64, count=total)
-        scores = np.fromiter(
-            chain.from_iterable(m.values() for m in doc_maps),
-            dtype=np.float32, count=total)
-        if int(tokens.min()) < 0:  # OOV docs → exact string pipeline
-            docnos = np.array(list(chain.from_iterable(doc_maps)))
-            return self._make_buffer(list(qids), counts, docnos, scores)
-        return self._buffer_from_exact_tokens(list(qids), counts, tokens,
-                                              scores)
+        with obs.span("repro.tokenize"):
+            doc_maps = [run[q] for q in qids]
+            nq = len(qids)
+            counts = np.fromiter(map(len, doc_maps), dtype=np.int64, count=nq)
+            total = int(counts.sum())
+            tokens = np.fromiter(
+                map(self._tok.get, chain.from_iterable(doc_maps), repeat(-1)),
+                dtype=np.int64, count=total)
+            strings = total if total and int(tokens.min()) < 0 else 0
+            obs.count("repro.tokenize.rows", total)
+            if obs.recording():
+                obs.count("repro.tokenize.oov",
+                          int(np.count_nonzero(tokens < 0)))
+            obs.count("repro.tokenize.strings", strings)
+            if not total:
+                return self._make_buffer(list(qids), counts,
+                                         np.empty(0, dtype="U1"),
+                                         np.empty(0, dtype=np.float32))
+            scores = np.fromiter(
+                chain.from_iterable(m.values() for m in doc_maps),
+                dtype=np.float32, count=total)
+            if strings:  # OOV docs → exact string pipeline
+                docnos = np.array(list(chain.from_iterable(doc_maps)))
+                return self._make_buffer(list(qids), counts, docnos, scores)
+            return self._buffer_from_exact_tokens(list(qids), counts, tokens,
+                                                  scores)
 
     def _make_buffer(self, qids: List[str], counts: np.ndarray,
                      docnos: np.ndarray, scores: np.ndarray) -> RunBuffer:

@@ -37,8 +37,8 @@ from typing import List, NamedTuple, Optional
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Record", "span", "mark", "count", "records", "dropped",
-           "clear"]
+__all__ = ["Record", "span", "mark", "count", "recording", "records",
+           "dropped", "clear"]
 
 #: records kept at most; spans past it are counted by :func:`dropped`
 CAPACITY = 1 << 20
@@ -142,6 +142,12 @@ def count(name: str, value: Optional[int]) -> None:
         stack = _stack()
         now = time.perf_counter_ns()
         _keep(name, stack[-1] if stack else None, now, now, value)
+
+
+def recording() -> bool:
+    """Whether the profiler records: a count whose value costs a pass over
+    the data is computed only then."""
+    return _is_enabled()
 
 
 def records() -> List[Record]:

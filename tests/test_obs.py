@@ -163,7 +163,8 @@ def test_the_xplane_holds_the_layers_by_name(ev, tmp_path):
     names = [e.name for plane in ProfileData.from_file(paths[0]).planes
              if plane.name == "/host:CPU" for line in plane.lines
              for e in line.events if e.name.startswith("repro.")]
-    assert names == ["repro.evaluate"] + LAYERS
+    # the run's tokenization, then the evaluation and its layers
+    assert names == ["repro.tokenize", "repro.evaluate"] + LAYERS
 
 
 def test_records_past_the_capacity_are_counted(tmp_path, monkeypatch):
